@@ -14,6 +14,7 @@ import traceback
 from . import (bench_alpha_ablation, bench_build, bench_concurrent,
                bench_io_cost, bench_merge_recall, bench_merge_vs_rebuild,
                bench_recall_stability, bench_throughput, bench_update_path)
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = [
     ("fig1_fig2_recall_stability", bench_recall_stability),
@@ -33,6 +34,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failures = 0

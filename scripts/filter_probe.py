@@ -30,7 +30,7 @@ import os
 import sys
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
-os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
+os.environ["JAX_PLATFORMS"] = "cpu"   # a CPU probe: never take the chip
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "src"))
 

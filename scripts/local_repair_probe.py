@@ -26,7 +26,7 @@ pass, mirroring shard_probe.py / disk_probe.py.
 import os
 import sys
 
-os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
+os.environ["JAX_PLATFORMS"] = "cpu"   # a CPU probe: never take the chip
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "src"))
 

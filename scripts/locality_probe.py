@@ -30,7 +30,7 @@ import os
 import sys
 import tempfile
 
-os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
+os.environ["JAX_PLATFORMS"] = "cpu"   # a CPU probe: never take the chip
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "src"))
 

@@ -24,11 +24,12 @@ of these contracts run in-process in ``tests/test_scheduler.py`` and
 ``tests/test_serving.py``; this probe is the multi-device half, invoked
 as a subprocess there and as a dedicated CI step.
 """
+import dataclasses
 import os
 import sys
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
-os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
+os.environ["JAX_PLATFORMS"] = "cpu"   # a CPU probe: never take the chip
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "src"))
 
@@ -118,6 +119,16 @@ def probe_replicas() -> None:
     np.testing.assert_array_equal(ids, ref_ids)
     np.testing.assert_array_equal(d, ref_d)
     print("# 2 replicas x 2 shards: composition bit-identical")
+
+    # The kernel-routed engine (what a TPU runs): replicas take the same
+    # distance kernels as the direct program.
+    sys_.cfg = dataclasses.replace(sys_.cfg, index=dataclasses.replace(
+        sys_.cfg.index, use_kernel=True))
+    kref_ids, kref_d = sys_.search_batch(q, k=5)
+    ids, d = ReplicaSet(sys_, 4).search_batch(q, k=5)
+    np.testing.assert_array_equal(ids, kref_ids)
+    np.testing.assert_array_equal(d, kref_d)
+    print("# use_kernel=True: 4 replicas bit-identical to direct")
 
     # Generation swap under routing: background merge, then re-serve.
     sys_, q = build_system(batch_queries=4, background_merge=True)

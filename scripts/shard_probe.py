@@ -13,7 +13,9 @@ contracts end to end on REAL multi-device sharding:
      (`SystemStats.search_dispatches` += 1 per micro-batch);
   3. `batch_queries` micro-batching chunks/pads without changing any result
      and counts ceil(B/N) programs;
-  4. per-query serving (B=1 calls) matches the batch, row for row.
+  4. per-query serving (B=1 calls) matches the batch, row for row;
+  5. with the kernels routed (``use_kernel=True``, the TPU engine), 4
+     shards still match the unsharded kernel program bit for bit.
 
 Exits non-zero on the first violated contract.  The same invariants run
 in-process (single device, shards=1) in ``tests/test_serving.py``; this
@@ -25,7 +27,7 @@ import os
 import sys
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
-os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
+os.environ["JAX_PLATFORMS"] = "cpu"   # a CPU probe: never take the chip
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "src"))
 
@@ -94,6 +96,17 @@ def main() -> int:
         np.testing.assert_array_equal(ids[0], ref_ids[i])
         np.testing.assert_array_equal(d[0], ref_d[i])
     print("# per-query == batched, row for row")
+
+    # 5: the kernel-routed engine (what a TPU runs): the sharded lane must
+    # take the same distance kernels as the unsharded program.
+    kcfg = dataclasses.replace(sys_.cfg.index, use_kernel=True)
+    sys_.cfg = dataclasses.replace(sys_.cfg, index=kcfg, shard_lti=0)
+    kref_ids, kref_d = sys_.search_batch(q, k=5)
+    sys_.cfg = dataclasses.replace(sys_.cfg, shard_lti=4)
+    ids, d = sys_.search_batch(q, k=5)
+    np.testing.assert_array_equal(ids, kref_ids)
+    np.testing.assert_array_equal(d, kref_d)
+    print("# use_kernel=True: shards=4 bit-identical to unsharded")
     print("# SHARD-PROBE OK")
     return 0
 

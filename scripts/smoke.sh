@@ -2,9 +2,9 @@
 # CI smoke: docs reference check + tier-1 tests + a short kernel-path
 # throughput probe.
 #
-# REPRO_PALLAS_INTERPRET=1 forces the Pallas kernels through the interpreter,
-# so kernel-path regressions (shape/padding/semantics) surface on any CPU box
-# without a TPU.  The bench probe builds a small LTI and runs the beam-width
+# JAX_PLATFORMS=cpu keeps every step on the CPU, where the Pallas kernels run
+# through the interpreter, so kernel-path regressions (shape/padding/
+# semantics) surface on any box without a TPU.  The bench probe builds a small LTI and runs the beam-width
 # sweep with the kernels enabled — ~30s end to end.
 #
 # `smoke.sh --shards` runs the sharded-serving probe instead: 4 fake host
@@ -46,7 +46,7 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH}
-export REPRO_PALLAS_INTERPRET=1
+export JAX_PLATFORMS=cpu
 
 if [[ "${1:-}" == "--shards" ]]; then
   XLA_FLAGS="--xla_force_host_platform_device_count=4" \

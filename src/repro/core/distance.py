@@ -13,6 +13,10 @@ import jax.numpy as jnp
 
 INF = jnp.inf
 INVALID = -1  # sentinel node id
+# f32 matmuls on a TPU default to one bf16 pass; distances here rank
+# neighbors and define ground truth, so they ask for full f32 precision
+# (a no-op on CPU, where f32 is already exact).
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def l2_sq(a: jax.Array, b: jax.Array) -> jax.Array:
@@ -27,7 +31,7 @@ def l2_sq_batch(queries: jax.Array, points: jax.Array) -> jax.Array:
     x = points.astype(jnp.float32)
     qn = jnp.sum(q * q, axis=-1, keepdims=True)          # [Q, 1]
     xn = jnp.sum(x * x, axis=-1)                          # [N]
-    d = qn - 2.0 * (q @ x.T) + xn[None, :]
+    d = qn - 2.0 * jnp.matmul(q, x.T, precision=HIGHEST) + xn[None, :]
     return jnp.maximum(d, 0.0)
 
 

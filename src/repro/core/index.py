@@ -350,13 +350,34 @@ def build(vectors: np.ndarray | jax.Array, cfg: IndexConfig,
     return state
 
 
-def brute_force(vectors: jax.Array, mask: jax.Array, queries: jax.Array,
-                k: int) -> jax.Array:
-    """Exact k-NN over masked rows — ground truth for every recall number."""
+def _masked_topk(vectors: jax.Array, mask: jax.Array, queries: jax.Array,
+                 k: int):
     from .distance import l2_sq_batch
     d = l2_sq_batch(queries, vectors)
-    d = jnp.where(mask[None, :], d, jnp.inf)
-    return jax.lax.top_k(-d, k)[1]
+    return jax.lax.top_k(jnp.where(mask[None, :], -d, -jnp.inf),
+                         min(k, vectors.shape[0]))
+
+
+def brute_force(vectors: jax.Array, mask: jax.Array, queries: jax.Array,
+                k: int, chunk: int = 65536) -> jax.Array:
+    """Exact k-NN over masked rows — ground truth for every recall number.
+
+    Distances are full f32 (``l2_sq_batch`` asks for HIGHEST precision).
+    Rows are scanned ``chunk`` at a time with a running top-k, so a large
+    corpus never materializes the whole [Q, N] distance matrix.  Ties go to
+    the lower row index, exactly as one ``top_k`` over all rows: the running
+    list precedes each new chunk in the merge.
+    """
+    n = vectors.shape[0]
+    best_v, best_i = _masked_topk(vectors[:chunk], mask[:chunk], queries, k)
+    for lo in range(chunk, n, chunk):
+        v, i = _masked_topk(vectors[lo:lo + chunk], mask[lo:lo + chunk],
+                            queries, k)
+        merged_v = jnp.concatenate([best_v, v], axis=1)
+        merged_i = jnp.concatenate([best_i, i + lo], axis=1)
+        best_v, pos = jax.lax.top_k(merged_v, min(k, merged_v.shape[1]))
+        best_i = jnp.take_along_axis(merged_i, pos, axis=1)
+    return best_i
 
 
 def recall_at_k(found_ids: jax.Array, true_ids: jax.Array) -> jax.Array:

@@ -16,33 +16,47 @@ import jax
 import jax.numpy as jnp
 
 from .config import PQConfig
+from .distance import HIGHEST
 
 
 class PQCodebook(NamedTuple):
     centroids: jax.Array   # [m, ksub, dsub] float32
 
 
-def _assign(x_sub: jax.Array, cent: jax.Array) -> jax.Array:
-    """x_sub [N, m, dsub], cent [m, ksub, dsub] -> codes [N, m] int32."""
-    # ||x - c||^2 = ||x||^2 - 2 x.c + ||c||^2 ; argmin over ksub
-    xc = jnp.einsum("nmd,mkd->nmk", x_sub, cent)
-    cn = jnp.sum(cent * cent, axis=-1)                      # [m, ksub]
-    return jnp.argmin(cn[None] - 2.0 * xc, axis=-1).astype(jnp.int32)
+def _assign(x: jax.Array, cent: jax.Array) -> jax.Array:
+    """x [N, dim], cent [m, ksub, dsub] -> codes [N, m] int32.
+
+    Squared distances are summed from the differences, one dsub component
+    at a time (``x[:, t::dsub]`` is component t of every subvector), in
+    plain f32 elementwise arithmetic.  No matmul: on the v5e the
+    ``||c||^2 - 2 x.c`` form at HIGHEST precision came out wrong (as an
+    einsum and as a 2-D matmul alike; codes matched the CPU's on 13-25% of
+    entries), and at default precision it rounds x to bf16."""
+    m, ksub, dsub = cent.shape
+    d2 = jnp.zeros((x.shape[0], m, ksub), jnp.float32)
+    for t in range(dsub):
+        diff = x[:, t::dsub, None] - cent[None, :, :, t]    # [N, m, ksub]
+        d2 = d2 + diff * diff
+    return jnp.argmin(d2, axis=-1).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def train_pq(data: jax.Array, cfg: PQConfig) -> PQCodebook:
     """Lloyd's k-means per subspace (vectorised across all m subspaces)."""
     n = data.shape[0]
-    x = data.astype(jnp.float32).reshape(n, cfg.m, cfg.dsub)
+    x = data.astype(jnp.float32)                            # [N, dim]
     key = jax.random.PRNGKey(cfg.seed)
     init_idx = jax.random.choice(key, n, (cfg.ksub,), replace=n < cfg.ksub)
-    cent = jnp.transpose(x[init_idx], (1, 0, 2))            # [m, ksub, dsub]
+    cent = jnp.transpose(x[init_idx].reshape(cfg.ksub, cfg.m, cfg.dsub),
+                         (1, 0, 2))                         # [m, ksub, dsub]
 
     def step(cent, _):
         codes = _assign(x, cent)                            # [N, m]
         oh = jax.nn.one_hot(codes, cfg.ksub, dtype=jnp.float32)  # [N, m, k]
-        sums = jnp.einsum("nmk,nmd->mkd", oh, x)
+        # Per-centroid sums, one dsub component at a time, as f32 sums of
+        # one-hot-masked values (no matmul; see _assign).
+        sums = jnp.stack([jnp.sum(oh * x[:, t::cfg.dsub, None], axis=0)
+                          for t in range(cfg.dsub)], axis=-1)  # [m, k, dsub]
         cnts = jnp.sum(oh, axis=0)                          # [m, k]
         new = sums / jnp.maximum(cnts, 1.0)[..., None]
         cent = jnp.where((cnts > 0)[..., None], new, cent)  # keep empty as-is
@@ -55,19 +69,28 @@ def train_pq(data: jax.Array, cfg: PQConfig) -> PQCodebook:
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def encode(codebook: PQCodebook, data: jax.Array, cfg: PQConfig) -> jax.Array:
     """Vectors -> uint8 codes [N, m]."""
-    n = data.shape[0]
-    x = data.astype(jnp.float32).reshape(n, cfg.m, cfg.dsub)
+    x = data.astype(jnp.float32)
     return _assign(x, codebook.centroids).astype(jnp.uint8)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def decode(codebook: PQCodebook, codes: jax.Array, cfg: PQConfig) -> jax.Array:
-    """Codes -> reconstructed vectors [N, dim] (used for prune distances)."""
-    c = codes.astype(jnp.int32)                             # [N, m]
-    recon = jnp.take_along_axis(
-        codebook.centroids[None],                           # [1, m, k, dsub]
-        c[:, :, None, None], axis=2)[:, :, 0, :]            # [N, m, dsub]
-    return recon.reshape(codes.shape[0], cfg.m * cfg.dsub)
+    """Codes -> reconstructed vectors [N, dim] (used for prune distances).
+
+    Output column j is centroid element ``[j // dsub, code, j % dsub]``:
+    one gather from the flattened codebook straight into the [N, dim]
+    layout.  No [N, m, dsub] intermediate: on a TPU its dsub-wide minor
+    axis pads to 128 lanes, a 32x blow-up (32 GiB at a 2M-row LTI).  The
+    per-column code is the [N, m] codes times a 0/1 expansion matrix
+    (exact: one nonzero term, integers below 2^8)."""
+    m, ksub, dsub = codebook.centroids.shape
+    j = jnp.arange(m * dsub)
+    expand = (j[None, :] // dsub == jnp.arange(m)[:, None]).astype(
+        jnp.float32)                                        # [m, dim]
+    c = jnp.matmul(codes.astype(jnp.float32), expand,
+                   precision=HIGHEST).astype(jnp.int32)     # [N, dim]
+    flat = codebook.centroids.reshape(-1)
+    return flat[(j // dsub * ksub + c) * dsub + j % dsub]
 
 
 def lut(codebook: PQCodebook, query: jax.Array) -> jax.Array:

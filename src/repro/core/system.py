@@ -313,6 +313,7 @@ class FreshDiskANN:
         self._flush_seq = 0                  # locality-order seed per flush
         self._merge_inflight = 0             # staged points being merged now
         self._merge_thread: Optional[threading.Thread] = None
+        self._merge_error: Optional[BaseException] = None  # from the thread
         self._force_global_repair = False    # set when a reachability probe
         #   after a localized repair degrades past cfg.reach_escalate_frac
         #   above the baseline; the next Delete phase then runs the global
@@ -650,8 +651,8 @@ class FreshDiskANN:
 
         Three caches: the 1-axis data mesh (per shard count), the
         ``graph.shard_lti`` placement (keyed by LTI graph/codes identity —
-        a merge swaps them and misses), and the jitted step per static
-        (k, kk, L, W, rerank) tuple.
+        a merge swaps them and misses), and the jitted step per (index
+        config, k, kk, L, W, rerank) — the config decides the engine.
         """
         from ..distributed.sharding import data_mesh
         from ..serving.steps import make_sharded_unified_step
@@ -669,7 +670,7 @@ class FreshDiskANN:
                                mesh=self._shard_mesh)
             place = (stack.lti, stack.codes, sg, sc)
             self._shard_place = place
-        key = (k, kk, L, W, rerank)
+        key = (self.cfg.index, k, kk, L, W, rerank)
         step = self._shard_steps.get(key)
         if step is None:
             step = make_sharded_unified_step(
@@ -1141,14 +1142,24 @@ class FreshDiskANN:
         if background:
             if self._merge_thread and self._merge_thread.is_alive():
                 return
-            self._merge_thread = threading.Thread(target=self._merge_impl)
+            self._merge_thread = threading.Thread(target=self._merge_worker)
             self._merge_thread.start()
         else:
             self._merge_impl()
 
+    def _merge_worker(self) -> None:
+        try:
+            self._merge_impl()
+        except BaseException as e:
+            self._merge_error = e
+
     def wait_merge(self) -> None:
+        """Join the background merge; re-raise the exception it died of."""
         if self._merge_thread:
             self._merge_thread.join()
+        err, self._merge_error = self._merge_error, None
+        if err is not None:
+            raise err
 
     def _merge_impl(self) -> None:
         with self._merge_lock:

@@ -1,4 +1,4 @@
-"""Activation-sharding context + the ``shard_map`` version shim.
+"""Activation-sharding context.
 
 Model code is mesh-agnostic; the launch layer wraps step functions in
 ``activation_sharding(mesh)`` so that ``shard_act(x, 'batch', None, ...)``
@@ -9,11 +9,6 @@ Dim tags: 'batch' -> the ('pod','data') super-axis; 'model' -> the tensor
 axis; None -> unsharded.  A tag is dropped automatically when the dim size
 is not divisible by the mesh axis size, so the same model code is legal for
 every architecture/shape combination.
-
-``shard_map_compat`` is the one place that papers over the jax 0.4/0.5 API
-drift for explicitly-SPMD programs (the distributed ANN steps in
-``launch.ann_steps`` and the mesh-sharded LTI serving lane in
-``serving.steps`` both route through it — see docs/SERVING.md).
 """
 from __future__ import annotations
 
@@ -24,16 +19,6 @@ from typing import Optional
 import jax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
-
-# jax >= 0.5 exposes shard_map at the top level and calls the replication
-# check ``check_vma``; 0.4.x has it under experimental with ``check_rep``.
-if hasattr(jax, "shard_map"):
-    shard_map_compat = jax.shard_map
-else:                                           # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    def shard_map_compat(f, *, check_vma=True, **kw):
-        return _shard_map_04(f, check_rep=check_vma, **kw)
 
 _CTX: contextvars.ContextVar = contextvars.ContextVar(
     "activation_sharding", default=None)

@@ -1,7 +1,8 @@
 """Pallas TPU kernels for the FreshDiskANN compute hot-spots.
 
 Three kernels, each with a pure-jnp oracle in ``ref.py`` and a jit'd public
-wrapper in ``ops.py`` (which falls back to interpret mode on CPU):
+wrapper in ``ops.py`` (which runs them in interpret mode exactly when the
+backend is the CPU):
 
   pq_adc       — asymmetric distance computation over PQ codes.  The paper's
                  single hottest op: every navigation step of the SSD/LTI index

@@ -7,12 +7,13 @@ rebuilds its row from
 
 followed by RobustPrune (paper Algorithm 4).  The jnp engine materializes
 the masked candidate list, gathers, and then pays R separate prune rounds
-per node; this kernel fuses the whole block step into ONE launch: the
-neighbor-of-deleted-neighbor candidate assembly (kept-edge and expansion
-masks), the anchor-distance masking, all R prune rounds (shared
-``robust_prune._prune_rounds``, vectorized across the block's rows), and
-the final changed-row select (untouched nodes — dead, or no deleted
-neighbor — keep their row).  One launch per block is the same HBM->VMEM
+per node; here the whole block step is one jitted program around ONE prune
+launch: the neighbor-of-deleted-neighbor candidate assembly (kept-edge and
+expansion masks) and the anchor-distance masking are elementwise XLA
+prologue, all R prune rounds run in the shared gridded prune launch
+(``robust_prune.prune_call``, one row group per grid step), and the
+changed-row select (untouched nodes — dead, or no deleted neighbor — keep
+their row) is the epilogue.  One launch per block is the same HBM->VMEM
 streaming unit as the paper's sequential SSD block pass.
 
 The HBM gathers stay OUTSIDE the kernel (XLA gathers in the engine): the
@@ -38,13 +39,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from .robust_prune import _fp_cover, _prune_rounds, _sdc_cover
+from .robust_prune import prune_call
 
 
 def _assemble(row, nbr_del, exp, exp_ok, usable_c, d_p, p):
-    """Candidate assembly + anchor-distance masking (kernel-side half).
+    """Candidate assembly + anchor-distance masking.
 
     row [B, R], nbr_del [B, R] i32, exp [B, E] i32 (pre-gathered expansion
     rows, parent-major flattened, INVALID-padded past the real E_par * R
@@ -63,29 +63,13 @@ def _assemble(row, nbr_del, exp, exp_ok, usable_c, d_p, p):
     return raw, d_pm, changed
 
 
-def _fp_kernel(row_ref, nd_ref, exp_ref, eok_ref, us_ref, d_ref, v_ref,
-               p_ref, live_ref, out_ref, *, alpha, R):
-    raw, d_pm, changed = _assemble(row_ref[...], nd_ref[...], exp_ref[...],
-                                   eok_ref[...], us_ref[...], d_ref[...],
-                                   p_ref[...])
-    out, _ = _prune_rounds(d_pm, raw,
-                           _fp_cover(v_ref[...].astype(jnp.float32)),
-                           alpha=alpha, R=R)
-    out_ref[...] = jnp.where(changed & (live_ref[...] != 0), out,
-                             row_ref[...])
-
-
-def _sdc_kernel(row_ref, nd_ref, exp_ref, eok_ref, us_ref, d_ref, c_ref,
-                t_ref, p_ref, live_ref, out_ref, *, alpha, R):
-    raw, d_pm, changed = _assemble(row_ref[...], nd_ref[...], exp_ref[...],
-                                   eok_ref[...], us_ref[...], d_ref[...],
-                                   p_ref[...])
-    out, _ = _prune_rounds(d_pm, raw,
-                           _sdc_cover(c_ref[...],
-                                      t_ref[...].astype(jnp.float32)),
-                           alpha=alpha, R=R)
-    out_ref[...] = jnp.where(changed & (live_ref[...] != 0), out,
-                             row_ref[...])
+def _repair(row, nbr_del, exp, exp_ok, usable_c, d_p, payload, p, live, *,
+            alpha, R, interpret, tables=None):
+    raw, d_pm, changed = _assemble(row, nbr_del, exp, exp_ok, usable_c,
+                                   d_p, p)
+    out, _ = prune_call(d_pm, payload, raw, alpha=alpha, R=R,
+                        interpret=interpret, tables=tables)
+    return jnp.where(changed & (live != 0), out, row)
 
 
 @functools.partial(jax.jit, static_argnames=("alpha", "R", "interpret"))
@@ -102,11 +86,9 @@ def delete_repair_fp_kernel(row, nbr_del, exp, exp_ok, usable_c, d_p, vecs,
     """
     B, C = d_p.shape
     assert vecs.shape[:2] == (B, C) and usable_c.shape == (B, C)
-    return pl.pallas_call(
-        functools.partial(_fp_kernel, alpha=alpha, R=R),
-        out_shape=jax.ShapeDtypeStruct(row.shape, jnp.int32),
-        interpret=interpret,
-    )(row, nbr_del, exp, exp_ok, usable_c, d_p, vecs, p, live)
+    return _repair(row, nbr_del, exp, exp_ok, usable_c, d_p,
+                   vecs.astype(jnp.float32), p, live, alpha=alpha, R=R,
+                   interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("alpha", "R", "interpret"))
@@ -120,8 +102,6 @@ def delete_repair_sdc_kernel(row, nbr_del, exp, exp_ok, usable_c, d_p,
     """
     B, C = d_p.shape
     assert codes.shape[:2] == (B, C) and usable_c.shape == (B, C)
-    return pl.pallas_call(
-        functools.partial(_sdc_kernel, alpha=alpha, R=R),
-        out_shape=jax.ShapeDtypeStruct(row.shape, jnp.int32),
-        interpret=interpret,
-    )(row, nbr_del, exp, exp_ok, usable_c, d_p, codes, tables, p, live)
+    return _repair(row, nbr_del, exp, exp_ok, usable_c, d_p,
+                   codes.astype(jnp.int32), p, live, alpha=alpha, R=R,
+                   interpret=interpret, tables=tables.astype(jnp.float32))

@@ -35,6 +35,7 @@ def _l2_kernel(q_ref, x_ref, out_ref, acc_ref, *, n_dblocks: int):
     x = x_ref[...].astype(jnp.float32)                 # [BN, BD]
     cross = jax.lax.dot_general(
         q, x, dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)            # [BQ, BN]
     qn = jnp.sum(q * q, axis=1, keepdims=True)         # [BQ, 1]
     xn = jnp.sum(x * x, axis=1)[None, :]               # [1, BN]

@@ -1,8 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
 Responsibilities: pad inputs to block multiples, pick interpret mode on CPU
-(this container validates kernels with ``interpret=True``; on TPU the same
-code compiles to Mosaic), and slice padding back off.  Every wrapper is
+(the CPU backend validates kernels with ``interpret=True``; on TPU the same
+code compiles to Mosaic — ``tests/test_tpu_compile.py`` compiles every
+main-path kernel for a v5e chip), and slice padding back off.  Every wrapper is
 numerically interchangeable with its ``ref.py`` oracle — the per-kernel
 contracts (reference, shape/dtype/padding invariants, parity tests) are
 tabulated in docs/KERNELS.md.
@@ -10,7 +11,6 @@ tabulated in docs/KERNELS.md.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -25,10 +25,9 @@ from .robust_prune import robust_prune_fp_kernel, robust_prune_sdc_kernel
 
 
 def _interpret() -> bool:
-    force = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if force is not None:
-        return force not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+    """Interpret mode exactly when the backend is the CPU: a TPU always
+    runs the compiled Mosaic kernels."""
+    return jax.default_backend() == "cpu"
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int, fill) -> jax.Array:

@@ -41,6 +41,7 @@ def _adc_kernel(codes_ref, lut_ref, out_ref, *, ksub: int):
     acc = jax.lax.dot_general(
         onehot2, lut2,
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)            # [BN, BQ]
     out_ref[...] = acc.T                               # [BQ, BN]
 

@@ -28,7 +28,8 @@ def l2_distances_ref(queries: jax.Array, points: jax.Array) -> jax.Array:
     x = points.astype(jnp.float32)
     qn = jnp.sum(q * q, axis=-1, keepdims=True)
     xn = jnp.sum(x * x, axis=-1)
-    return jnp.maximum(qn - 2.0 * (q @ x.T) + xn[None, :], 0.0)
+    cross = jnp.matmul(q, x.T, precision=jax.lax.Precision.HIGHEST)
+    return jnp.maximum(qn - 2.0 * cross + xn[None, :], 0.0)
 
 
 def frontier_select_ref(cand_ids: jax.Array, cand_d: jax.Array,

@@ -22,7 +22,6 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..core import pq as pqm
-from ..distributed.ctx import shard_map_compat as _shard_map
 from ..core.config import IndexConfig, PQConfig
 from ..core.graph import GraphState
 from ..core.index import insert as mem_insert
@@ -129,7 +128,7 @@ def make_distributed_search(mesh: Mesh, cfg: IndexConfig, *, k: int,
 
     lti_specs = LTIState(graph=lti_specs.graph, codes=lti_specs.codes,
                          codebook=pqm.PQCodebook(P()))
-    return jax.jit(_shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=(lti_specs, P()),
         out_specs=(P(), P()), check_vma=False))
 
@@ -176,7 +175,7 @@ def make_distributed_insert(mesh: Mesh, cfg: IndexConfig,
 
     lti_in = LTIState(graph=lti_specs.graph, codes=lti_specs.codes,
                       codebook=pqm.PQCodebook(P()))
-    return jax.jit(_shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=(lti_in, P()), out_specs=lti_in,
         check_vma=False),
         donate_argnums=(0,))
@@ -219,7 +218,7 @@ def make_distributed_merge(mesh: Mesh, cfg: IndexConfig, pq_cfg: PQConfig,
 
     lti_in = LTIState(graph=lti_specs.graph, codes=lti_specs.codes,
                       codebook=pqm.PQCodebook(P()))
-    return jax.jit(_shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(lti_in, P(), P(), lti_specs.graph.deleted),
         out_specs=lti_in, check_vma=False),

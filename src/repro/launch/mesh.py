@@ -10,14 +10,9 @@ import jax
 
 
 def mesh_with_auto_axes(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with explicit Auto axis types where the installed
-    jax supports them (>= 0.5); on older versions Auto is already the default
-    and the kwarg/enum do not exist, so plain ``make_mesh`` is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+    """``jax.make_mesh`` with every axis of Auto type."""
+    return jax.make_mesh(shape, axes, axis_types=(
+        jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -16,6 +16,7 @@ from ..core.config import IndexConfig, PQConfig, SystemConfig
 from ..core.index import brute_force, recall_at_k
 from ..core.system import bootstrap_system
 from ..data.pipelines import vector_stream
+from .compile_cache import enable_compile_cache
 
 import jax.numpy as jnp
 
@@ -29,6 +30,7 @@ def main():
     ap.add_argument("--k", type=int, default=5)
     ap.add_argument("--wal-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     stream = vector_stream(args.points, args.dim, seed=3)
     base = next(stream)

@@ -181,7 +181,7 @@ class ReplicaSet:
 
         Cache discipline is ``system._sharded_program``'s, per replica:
         placement re-keys on LTI graph/codes identity (merge survival),
-        steps on the static shape tuple."""
+        steps on the index config + static shape tuple."""
         from ..core.graph import LaneStack, shard_lti
         from .steps import make_sharded_unified_step
         mesh = self.groups[r]
@@ -192,7 +192,7 @@ class ReplicaSet:
                                mesh=mesh)
             place = (stack.lti, stack.codes, sg, sc)
             self._place[r] = place
-        key = (k, kk, L, W, rerank)
+        key = (self.system.cfg.index, k, kk, L, W, rerank)
         step = self._steps[r].get(key)
         if step is None:
             step = make_sharded_unified_step(

@@ -96,7 +96,7 @@ class Ticket:
     completion."""
 
     __slots__ = ("query", "arrival", "deadline", "fspec", "ids", "dists",
-                 "completion", "missed", "done")
+                 "completion", "missed", "done", "error")
 
     def __init__(self, query: np.ndarray, arrival: float, deadline: float,
                  fspec=None):
@@ -109,6 +109,7 @@ class Ticket:
         self.completion: Optional[float] = None
         self.missed = False
         self.done = threading.Event()
+        self.error: Optional[BaseException] = None
 
     @property
     def latency(self) -> Optional[float]:
@@ -120,6 +121,8 @@ class Ticket:
                ) -> tuple[np.ndarray, np.ndarray]:
         if not self.done.wait(timeout):
             raise TimeoutError("request not served within timeout")
+        if self.error is not None:
+            raise self.error
         return self.ids, self.dists
 
 
@@ -180,6 +183,7 @@ class BatchScheduler:
         self._cond = threading.Condition(self._lock)
         self._thread: Optional[threading.Thread] = None
         self._running = False
+        self._error: Optional[BaseException] = None  # worker's failure
         # Occupancy accounting beyond the last-batch gauge: mean fill over
         # the scheduler's lifetime (benchmarks report it per run).
         self._occupancy_sum = 0.0
@@ -200,6 +204,10 @@ class BatchScheduler:
             else None
         tenant = fspec.tenant if fspec is not None else None
         with self._cond:
+            if self._error is not None:
+                # The worker died: nothing would ever serve this ticket.
+                raise RuntimeError("scheduler worker failed") \
+                    from self._error
             if len(self._queue) >= self.capacity:
                 self.stats.shed_requests += 1
                 return None
@@ -367,13 +375,20 @@ class BatchScheduler:
         self._thread.start()
 
     def stop(self, flush: bool = True) -> None:
-        """Stop the worker; by default serve whatever is still queued."""
+        """Stop the worker; by default serve whatever is still queued.
+
+        A dispatch that raised on the worker thread ended the loop; its
+        exception is re-raised here (before any flush), so a failed
+        device program never passes for a clean shutdown."""
         self._running = False
         with self._cond:
             self._cond.notify_all()
         if self._thread:
             self._thread.join()
             self._thread = None
+        err, self._error = self._error, None
+        if err is not None:
+            raise err
         if flush:
             self.flush()
 
@@ -393,4 +408,20 @@ class BatchScheduler:
                     self._cond.wait(timeout=close_at - now)
                     continue
                 batch = self._take_locked()
-            self.dispatch(batch)       # outside the lock: submits proceed
+            try:
+                self.dispatch(batch)   # outside the lock: submits proceed
+            except BaseException as e:
+                # The batch's callers, and every caller still queued, see
+                # the failure from result(); later submits raise it, and
+                # stop() re-raises it to whoever owns the scheduler.
+                with self._cond:
+                    self._error = e
+                    self._running = False
+                    failed = batch + list(self._queue)
+                    self._queue.clear()
+                    self._queued_by_tenant.clear()
+                    self.stats.queue_depth = 0
+                for t in failed:
+                    t.error = e
+                    t.done.set()
+                return

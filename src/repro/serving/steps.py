@@ -33,10 +33,10 @@ from jax.sharding import PartitionSpec as P
 from ..core import index as mem
 from ..core import pq as pqm
 from ..core.config import IndexConfig
-from ..core.distance import INVALID, l2_sq
+from ..core.distance import INVALID
 from ..core.graph import LaneStack
-from ..core.search import batch_distances, beam_search, topk_masked
-from ..distributed.ctx import shard_map_compat
+from ..core.search import (FullPrecisionBackend, PQBackend,
+                           batch_distances, beam_search, topk_masked)
 from ..distributed.sharding import lti_lane_specs
 from ..models import recsys as rec
 from ..models import transformer as tf
@@ -117,10 +117,11 @@ class ShardedRows:
 class ShardedADC:
     """Owner-computes PQ asymmetric distances (the sharded ``PQBackend``).
 
-    The owner evaluates ``pq.adc`` on its local code rows — the same
-    arithmetic, hence the same f32 bits, as the dense ``adc_gather`` — and
-    the psum adds exact zeros from every other shard (x + 0.0 == x for the
-    non-negative finite distances ADC produces).
+    The owner evaluates the dense ``PQBackend`` on its local code rows — the
+    same routine (``pq.adc``, or the ``adc_distances`` kernel under
+    ``use_kernel``), hence the same f32 bits — and the psum adds exact
+    zeros from every other shard (x + 0.0 == x for the non-negative finite
+    distances ADC produces).
     """
 
     def __init__(self, codes: jax.Array, codebook: jax.Array, offset,
@@ -136,7 +137,8 @@ class ShardedADC:
     def distances(self, ctx: jax.Array, ids: jax.Array, *,
                   use_kernel: bool = False) -> jax.Array:
         own, loc = _owned(ids, self.offset, self.codes.shape[0])
-        d = pqm.adc(self.codes[loc], ctx)
+        d = PQBackend(self.codes, None).distances(ctx, loc,
+                                                  use_kernel=use_kernel)
         d = jax.lax.psum(jnp.where(own, d, 0.0), self.axis)
         return jnp.where(ids >= 0, d, jnp.inf)
 
@@ -144,7 +146,9 @@ class ShardedADC:
 class ShardedExact:
     """Owner-computes exact squared-L2 (the sharded ``FullPrecisionBackend``)
     — used for the LTI lane's in-program full-precision rerank, whose
-    vector rows live sharded."""
+    vector rows live sharded.  The owner runs the dense
+    ``FullPrecisionBackend`` on its local rows, so its value carries the
+    same bits."""
 
     def __init__(self, vectors: jax.Array, offset, axis: str):
         self.vectors = vectors              # [n_local, d]
@@ -157,7 +161,8 @@ class ShardedExact:
     def distances(self, ctx: jax.Array, ids: jax.Array, *,
                   use_kernel: bool = False) -> jax.Array:
         own, loc = _owned(ids, self.offset, self.vectors.shape[0])
-        d = l2_sq(ctx[None, :], self.vectors[loc])
+        d = FullPrecisionBackend(self.vectors).distances(
+            ctx, loc, use_kernel=use_kernel)
         d = jax.lax.psum(jnp.where(own, d, 0.0), self.axis)
         return jnp.where(ids >= 0, d, jnp.inf)
 
@@ -171,12 +176,13 @@ def make_sharded_lti_lane(mesh, cfg: IndexConfig, *, k_lane: int, L: int,
     Returns a callable ``(graph, codes, codebook, queries) -> (slot_ids
     [B, k_lane], dists, hops [B], cmps [B])`` whose outputs are replicated
     and bit-identical to the unsharded lane of ``index.search_lanes`` —
-    counters included — for any shard count.  The lane runs the jnp engine
-    path (``use_kernel=False``); the Pallas kernels are bit-identical to it
-    by the docs/KERNELS.md contract, so parity with a kernel-routed
-    unsharded lane still holds.
+    counters included — for any shard count.  The lane routes through the
+    same engine as the unsharded one (``cfg.kernel_enabled()``): the ADC
+    and exact-distance kernels do not round like the jnp formulas, so a
+    lane on the other engine would drift from the unsharded program.
     """
     W = beam_width or cfg.beam_width
+    use_kernel = cfg.kernel_enabled()
     gspecs, cspec = lti_lane_specs(axis)
 
     def local(g, codes, codebook, queries):
@@ -186,7 +192,7 @@ def make_sharded_lti_lane(mesh, cfg: IndexConfig, *, k_lane: int, L: int,
         res = beam_search(g.adjacency, g.active, g.start, queries,
                           ShardedADC(codes, codebook, offset, axis),
                           L=L, max_visits=cfg.visits_bound(L),
-                          beam_width=W, use_kernel=False, source=src)
+                          beam_width=W, use_kernel=use_kernel, source=src)
         ok = shard_gather_mask(g.active & ~g.deleted, res.ids, offset, axis)
         dists = res.dists
         if rerank:
@@ -194,14 +200,14 @@ def make_sharded_lti_lane(mesh, cfg: IndexConfig, *, k_lane: int, L: int,
             # ``rerank_candidates`` contract), on the precomputed ok mask.
             dists = batch_distances(
                 ShardedExact(g.vectors, offset, axis), queries,
-                jnp.where(ok, res.ids, INVALID))
+                jnp.where(ok, res.ids, INVALID), use_kernel=use_kernel)
         ids, d = topk_masked(res.ids, dists, ok, k_lane)
         return ids, d, res.n_hops, res.n_cmps
 
-    return shard_map_compat(local, mesh=mesh,
-                            in_specs=(gspecs, cspec, P(), P()),
-                            out_specs=(P(), P(), P(), P()),
-                            check_vma=False)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(gspecs, cspec, P(), P()),
+                         out_specs=(P(), P(), P(), P()),
+                         check_vma=False)
 
 
 def make_sharded_unified_step(mesh, cfg: IndexConfig, *, k: int, k_lane: int,
@@ -222,15 +228,22 @@ def make_sharded_unified_step(mesh, cfg: IndexConfig, *, k: int, k_lane: int,
     lane = make_sharded_lti_lane(mesh, cfg, k_lane=k_lane, L=L,
                                  beam_width=beam_width, rerank=rerank,
                                  axis=axis)
+    # The temp lanes run replicated, but inside a shard_map all the same:
+    # the program spans the mesh, and XLA cannot partition a Mosaic kernel
+    # on its own.
+    temp_lanes = jax.shard_map(
+        lambda temps, queries: mem.search_lanes(
+            LaneStack(temps, None, None, None), queries, cfg, k=k_lane, L=L,
+            beam_width=beam_width),
+        mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P(), P(), P()),
+        check_vma=False)
 
     @jax.jit
     def step(stack: LaneStack, t_tabs, l_tab, t_drop, l_drop, queries):
         B = queries.shape[0]
         parts_i, parts_d, hops, cmps = [], [], [], []
         if stack.temps is not None:
-            tids, td, th, tc = mem.search_lanes(
-                LaneStack(stack.temps, None, None, None), queries, cfg,
-                k=k_lane, L=L, beam_width=beam_width)
+            tids, td, th, tc = temp_lanes(stack.temps, queries)
             ext, dd = mem.lanes_to_ext(t_tabs, t_drop, tids, td)
             parts_i.append(jnp.transpose(ext, (1, 0, 2)).reshape(B, -1))
             parts_d.append(jnp.transpose(dd, (1, 0, 2)).reshape(B, -1))

@@ -1,8 +1,6 @@
 """Beam-width search engine: W=1 parity against the legacy single-expansion
 engine, recall-vs-beamwidth monotonicity, and kernel-vs-reference equality of
 the batched distance path (``use_kernel`` on/off through ``kernels.ops``)."""
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,8 +15,6 @@ from repro.core.search import (FullPrecisionBackend, PQBackend,
                                batch_distances, beam_search)
 
 from conftest import DIM, N
-
-os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
 
 
 # --------------------------------------------------------------------------

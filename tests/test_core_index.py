@@ -1,6 +1,7 @@
 """FreshVamana core: build quality, insert/delete correctness, counters."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core.config import IndexConfig
 from repro.core.delete import consolidate_deletes, delete
@@ -17,6 +18,72 @@ def _recall(state, cfg, queries, k=5, L=None):
     mask = state.active & ~state.deleted
     gt = brute_force(state.vectors, mask, jnp.asarray(queries), k)
     return float(recall_at_k(ids, gt)), hops, cmps
+
+
+@pytest.mark.parametrize("chunk", [7, 100, 512])
+def test_brute_force_chunked_matches_one_topk(points, queries, chunk):
+    """The chunked exact reference equals one top_k over every row, ties
+    (duplicate vectors) and masked rows included."""
+    import jax
+    from repro.core.distance import l2_sq_batch
+    x = jnp.asarray(points).at[700].set(jnp.asarray(points[3]))
+    q = jnp.asarray(queries).at[0].set(jnp.asarray(points[3]))
+    mask = jnp.asarray(np.arange(len(points)) % 5 != 1)
+    d = jnp.where(mask[None, :], l2_sq_batch(q, x), jnp.inf)
+    want = jax.lax.top_k(-d, 5)[1]
+    got = brute_force(x, mask, q, 5, chunk=chunk)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert {3, 700} <= set(np.asarray(got[0]).tolist())
+
+
+def _exact_subspace_d2(x, cent):
+    """[N, m, ksub] squared distance of every subvector to every centroid,
+    in float64 straight from the differences."""
+    m, ksub, dsub = cent.shape
+    xs = x.astype(np.float64).reshape(len(x), m, 1, dsub)
+    return ((xs - cent.astype(np.float64)[None]) ** 2).sum(-1)
+
+
+def test_pq_encode_picks_the_nearest_centroid():
+    """Every code is the nearest centroid of its subvector (within f32
+    rounding of near-ties)."""
+    from repro.core import pq as pqm
+    from repro.core.config import PQConfig
+    cfg = PQConfig(dim=32, m=8, ksub=16)
+    n = 3000
+    rng = np.random.default_rng(n)
+    cent = rng.normal(size=(8, 16, 4)).astype(np.float32)
+    x = rng.normal(size=(n, 32)).astype(np.float32)
+    codes = np.asarray(pqm.encode(pqm.PQCodebook(jnp.asarray(cent)),
+                                  jnp.asarray(x), cfg)).astype(np.int64)
+    assert codes.shape == (n, 8)
+    d2 = _exact_subspace_d2(x, cent)
+    got = np.take_along_axis(d2, codes[..., None], axis=-1)[..., 0]
+    best = d2.min(-1)
+    np.testing.assert_allclose(got, best, rtol=1e-5, atol=1e-5)
+    assert np.mean(codes == d2.argmin(-1)) > 0.999
+
+
+def test_pq_train_step_is_the_mean_of_assigned_points():
+    """One more Lloyd step moves each used centroid to the mean of the
+    subvectors assigned to it by the previous codebook; unused centroids
+    stay put."""
+    import dataclasses
+    from repro.core import pq as pqm
+    from repro.core.config import PQConfig
+    cfg1 = PQConfig(dim=32, m=8, ksub=16, kmeans_iters=1)
+    cfg2 = dataclasses.replace(cfg1, kmeans_iters=2)
+    x = np.random.default_rng(5).normal(size=(600, 32)).astype(np.float32)
+    c1 = np.asarray(pqm.train_pq(jnp.asarray(x), cfg1).centroids)
+    c2 = np.asarray(pqm.train_pq(jnp.asarray(x), cfg2).centroids)
+    codes = _exact_subspace_d2(x, c1).argmin(-1)          # [N, m]
+    xs = x.reshape(600, 8, 4)
+    for a in range(8):
+        for k in range(16):
+            rows = xs[codes[:, a] == k, a]
+            want = rows.mean(0) if len(rows) else c1[a, k]
+            np.testing.assert_allclose(c2[a, k], want, rtol=1e-5,
+                                       atol=1e-5)
 
 
 def test_build_recall(built_index, index_cfg, queries):

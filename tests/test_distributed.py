@@ -139,8 +139,7 @@ x = np.linspace(-1, 1, 8 * 32).astype(np.float32).reshape(8, 32)
 def red(xs, key):
     return int8_all_gather_reduce({"g": xs}, key, "data")["g"]
 
-from repro.launch.ann_steps import _shard_map
-out = jax.jit(_shard_map(
+out = jax.jit(jax.shard_map(
     partial(red, key=jax.random.PRNGKey(0)),
     mesh=Mesh(np.array(jax.devices()).reshape(8), ("data",)),
     in_specs=P("data"), out_specs=P("data")))(x.reshape(8, 32))
@@ -154,7 +153,7 @@ print(json.dumps({"recall": recall, "elastic_ok": bool(ok_shard),
 
 @pytest.fixture(scope="module")
 def multidev_result():
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", _MULTIDEV_SCRIPT],
                          capture_output=True, text=True, env=env,
                          timeout=1200)

@@ -209,6 +209,32 @@ def test_robust_prune_block_matches_per_row():
         assert int(g_cnt[b]) == int(one_cnt[0])
 
 
+@pytest.mark.parametrize("flavor", ["fp", "sdc"])
+@pytest.mark.parametrize("rows_per_step", [1, 3, 8])
+def test_robust_prune_row_groups_match_one_step(flavor, rows_per_step):
+    """The compiled path grids the block into groups of G rows (padding the
+    last group with inert rows); any grouping must equal the one-step
+    launch the interpreter uses."""
+    from repro.kernels.robust_prune import prune_call
+    B, C, R = 10, 40, 8
+    if flavor == "fp":
+        cases = [_prune_case(70 + i, C, 16) for i in range(B)]
+        d_p, payload, ids, ok = [jnp.stack(x) for x in zip(*cases)]
+        tables = None
+    else:
+        cases = [_sdc_case(80 + i, C, 8, 16) for i in range(B)]
+        d_p, payload, tables, ids, ok = [
+            jnp.stack(x) for x in zip(*cases)]
+        tables = tables[0]
+    dm = jnp.where(ok, d_p, jnp.inf)
+    ids = ids.astype(jnp.int32)
+    kw = dict(alpha=1.2, R=R, interpret=True, tables=tables)
+    want = prune_call(dm, payload, ids, **kw)
+    got = prune_call(dm, payload, ids, rows_per_step=rows_per_step, **kw)
+    for w, g in zip(want, got):
+        np.testing.assert_array_equal(np.asarray(w), np.asarray(g))
+
+
 def _repair_case(seed, N, R, d, cap=None):
     """An Algorithm-4 node repair input over a small random graph."""
     r = np.random.default_rng(seed)

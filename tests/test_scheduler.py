@@ -208,6 +208,43 @@ def test_worker_thread_serves_on_wall_clock(points, queries):
         sched.stop()
 
 
+def test_worker_thread_failure_surfaces_in_stop():
+    """A dispatch that raises on the worker thread fails its tickets'
+    result(), fails every ticket still queued behind it, makes later
+    submits raise, and is re-raised by stop() — the loop does not die
+    silently and leave callers waiting or the owner with success."""
+    import threading
+    from types import SimpleNamespace
+    from repro.core.config import IndexConfig, PQConfig, SystemConfig
+    from repro.core.system import SystemStats
+
+    cfg = SystemConfig(index=IndexConfig(capacity=64, dim=DIM),
+                       pq=PQConfig(dim=DIM, m=8), batch_queries=2)
+    fake = SimpleNamespace(cfg=cfg, stats=SystemStats())
+    entered, release = threading.Event(), threading.Event()
+
+    def serve(qs, k, **kw):
+        entered.set()
+        release.wait(60.0)
+        raise RuntimeError("search program failed")
+
+    sched = BatchScheduler(fake, k=1, serve=serve)
+    sched.start()
+    tickets = [sched.submit(np.zeros(DIM, np.float32)) for _ in range(2)]
+    assert entered.wait(60.0)           # the first batch is in dispatch
+    queued = sched.submit(np.zeros(DIM, np.float32))
+    release.set()
+    for t in tickets + [queued]:
+        with pytest.raises(RuntimeError, match="search program failed"):
+            t.result(timeout=60.0)
+    with pytest.raises(RuntimeError, match="worker failed") as info:
+        sched.submit(np.zeros(DIM, np.float32))
+    assert "search program failed" in str(info.value.__cause__)
+    assert sched.pending == 0
+    with pytest.raises(RuntimeError, match="search program failed"):
+        sched.stop()
+
+
 # ----------------------------------------------------------- backpressure
 
 def test_backpressure_sheds_beyond_capacity(points, queries):

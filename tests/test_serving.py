@@ -215,7 +215,7 @@ def test_shard_invariance_on_fake_devices(n_dev):
     chunk/pad invariant.  (A subprocess because the device census is fixed
     at jax import.)"""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
     env.pop("PYTHONPATH", None)               # probe inserts src/ itself
     out = subprocess.run(
@@ -340,7 +340,7 @@ def test_serving_invariance_on_fake_devices():
     replicas-x-shards composition, round-robin accounting, and routing
     survival across a background merge."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env.pop("PYTHONPATH", None)               # probe inserts src/ itself
     out = subprocess.run(

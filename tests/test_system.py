@@ -180,6 +180,30 @@ def test_background_merge_concurrent_search(points, queries):
     assert (np.asarray(ids) >= -1).all()
 
 
+def test_background_merge_failure_surfaces_in_wait_merge(points,
+                                                         monkeypatch):
+    """An exception inside the background merge thread is re-raised by
+    wait_merge() (once), and the system keeps serving the pre-merge
+    state: a failed merge on the device must not pass for a clean run."""
+    sys_ = bootstrap_system(points[:300], np.arange(300), _sys_cfg())
+    for i in range(150):
+        sys_.insert(7000 + i, points[300 + i])
+    sys_.ro.append(sys_.rw)
+    sys_.rw = sys_._new_temp()
+
+    def boom(ro, t0):
+        raise RuntimeError("merge failed on the device")
+
+    monkeypatch.setattr(sys_, "_merge_body", boom)
+    sys_.merge(background=True)
+    with pytest.raises(RuntimeError, match="merge failed on the device"):
+        sys_.wait_merge()
+    sys_.wait_merge()                       # the error is reported once
+    assert sys_.stats.merges == 0
+    ids, _ = sys_.search(points[300:301], k=1)
+    assert int(ids[0, 0]) == 7000
+
+
 # --------------------------------------------------- flush-path concurrency
 # The narrowed _insert_lock critical section (insert() holds it only for
 # WAL + buffer bookkeeping; the device-side flush runs under _flush_lock
