@@ -60,7 +60,7 @@ def main(quick: bool = False):
         live.pop(victim)
         # Search every 1/16th of the stream: each call is 4 micro-batches
         # of 8, each one a sample in stats.search_latency (insert latency
-        # samples land in stats.insert_latency via record_latency).
+        # samples land in stats.insert_latency).
         if (i + 1) % (updates // 16) == 0:
             ids, _ = sys_.search_batch(q, k=5)
         if (i + 1) % (updates // 4) == 0:     # recall needs ground truth

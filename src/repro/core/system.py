@@ -34,17 +34,20 @@ External ids are user-provided int64s; the system maps them to (tier, slot).
 """
 from __future__ import annotations
 
+import ctypes
 import dataclasses
 import os
 import pickle
 import threading
 import time
+from contextvars import ContextVar
 from dataclasses import dataclass, field
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import autotune
 from . import index as mem
@@ -72,6 +75,36 @@ class _Temp:
 
 
 LATENCY_RESERVOIR = 1024
+
+# The serving micro-batch being dispatched: ``BatchScheduler.dispatch`` sets
+# its sequence number for the duration of the serve call, and every search
+# span of that call carries it as ``batch`` (-1 outside the scheduler).
+# docs/SERVING.md, "Tracing", lists the spans.
+serving_batch: ContextVar[int] = ContextVar("serving_batch", default=-1)
+
+
+def lane_hops_metadata(span: TraceAnnotation, hops) -> None:
+    """Put the LTI lane's hop counts (the last row of a unified search's
+    ``hops`` [T, B]) on a ``fdann.search.device`` span: ``max_hops`` is the
+    trip count of the vmapped beam loop.  Reads a device value, so callers
+    run it only under ``TraceAnnotation.is_enabled()``."""
+    h = np.asarray(hops)[-1]
+    span.set_metadata(max_hops=int(h.max()), p50_hops=float(np.median(h)))
+
+
+def name_os_thread(name: str) -> None:
+    """Name the calling thread at the OS level (Linux ``PR_SET_NAME``, at
+    most 15 bytes), so a profiler trace shows its spans on a line of that
+    name; Python's ``threading`` names only its own object.  A no-op where
+    the C library has no ``prctl``."""
+    try:
+        prctl = ctypes.CDLL(None).prctl
+    except (AttributeError, OSError):
+        return
+    prctl.argtypes = [ctypes.c_int, ctypes.c_char_p, ctypes.c_ulong,
+                      ctypes.c_ulong, ctypes.c_ulong]
+    prctl.restype = ctypes.c_int
+    prctl(15, name.encode()[:15], 0, 0, 0)      # PR_SET_NAME
 
 
 class Reservoir:
@@ -221,18 +254,6 @@ class SystemStats:
         default_factory=lambda: Reservoir(seed=2), repr=False)
     flush_latency: Reservoir = field(
         default_factory=lambda: Reservoir(seed=3), repr=False)
-
-    def record_latency(self, seconds: float) -> None:
-        self.insert_latency.record(seconds)
-
-    # Back-compat views of the insert reservoir's previous field names.
-    @property
-    def insert_latencies(self) -> list:
-        return self.insert_latency.sample
-
-    @property
-    def latencies_seen(self) -> int:
-        return self.insert_latency.seen
 
     def serving_snapshot(self) -> dict:
         """One structured view of the serving surface: p50/p99 for each
@@ -424,7 +445,7 @@ class FreshDiskANN:
                 self._delete_epoch += 1  # drop-mask caches must see the revive
             full = len(self._insert_buf_id) >= self.cfg.insert_batch
         self.stats.inserts += 1
-        self.stats.record_latency(time.perf_counter() - t0)
+        self.stats.insert_latency.record(time.perf_counter() - t0)
         if full:
             self._flush_inserts()
         self._maybe_rollover()
@@ -498,7 +519,7 @@ class FreshDiskANN:
         filter that matches everything returns bit-identical (ids, dists)
         to the unfiltered call, and hops/cmps never change.
         """
-        self._flush_inserts()
+        self._search_flush()
         fspec = filter if filter is not None and not filter.is_empty \
             else None
         L = L or self.cfg.index.L_search
@@ -560,34 +581,33 @@ class FreshDiskANN:
         already done by ``search_batch``)."""
         q = jnp.asarray(queries, jnp.float32)
         nq = queries.shape[0]
-        rw_t, ro_temps, lti_entry = self._capture_lanes()
+        (rw_t, ro_temps, lti_entry), bundle, drop = self._prepare_batch(fspec)
         if rw_t is None and not ro_temps and lti_entry is None:
             return self._aggregate([], k, nq)
-        if self.cfg.batch_fanout:
-            bundle = self._lane_bundle(rw_t, ro_temps, lti_entry)
-            if bundle is not None:
-                key, stack, t_tabs, l_tab, tables_np, label_tabs = bundle
-                if fspec is None:
-                    t_drop, l_drop = self._drop_mask(key, tables_np)
-                else:
-                    t_drop, l_drop = self._filter_drop(
-                        key, tables_np, label_tabs, fspec)
-                # rerank only matters to the PQ lane; with no LTI lane it
-                # would be dead compute.
-                do_rerank = self.cfg.rerank and lti_entry is not None
+        if bundle is not None:
+            _, stack, t_tabs, l_tab, _, _ = bundle
+            t_drop, l_drop = drop
+            # rerank only matters to the PQ lane; with no LTI lane it would
+            # be dead compute.
+            do_rerank = self.cfg.rerank and lti_entry is not None
+            with TraceAnnotation("fdann.search.device",
+                                 batch=serving_batch.get()) as span:
                 if lti_entry is not None and self._shard_count():
                     step, sstack = self._sharded_program(
                         stack, k=k, kk=kk, L=L, W=W, rerank=do_rerank)
-                    ids, d, _, _ = step(sstack, t_tabs, l_tab, t_drop,
-                                        l_drop, q)
+                    ids, d, hops, _ = step(sstack, t_tabs, l_tab, t_drop,
+                                           l_drop, q)
                 else:
-                    ids, d, _, _ = mem.unified_search(
+                    ids, d, hops, _ = mem.unified_search(
                         stack, t_tabs, l_tab, t_drop, l_drop, q,
                         self.cfg.index, k=k, k_lane=kk, L=L, beam_width=W,
                         rerank=do_rerank)
                 self.stats.search_dispatches += 1
-                return (np.asarray(ids).astype(np.int64),
-                        np.asarray(d).astype(np.float32))
+                out = (np.asarray(ids).astype(np.int64),
+                       np.asarray(d).astype(np.float32))
+                if lti_entry is not None and span.is_enabled():
+                    lane_hops_metadata(span, hops)
+            return out
         # Sequential oracle: one device program per tier + host aggregation.
         cands: list[tuple[np.ndarray, np.ndarray]] = []   # (ext_ids, dists)
         if lti_entry is not None:
@@ -608,6 +628,34 @@ class FreshDiskANN:
                           self._slot_filter(ids, np.asarray(d),
                                             t.labels, fspec)))
         return self._aggregate(cands, k, nq)
+
+    def _search_flush(self) -> None:
+        """A search's leading flush of the insert buffer, its wait for the
+        flush lock included."""
+        with TraceAnnotation("fdann.search.flush",
+                             batch=serving_batch.get()) as span:
+            span.set_metadata(points=self._flush_inserts())
+
+    def _prepare_batch(self, fspec: Optional[FilterSpec]):
+        """Host preparation of one micro-batch: the lane capture, and on the
+        unified path the lane bundle and its drop masks.  Returns (captured
+        lanes, bundle or None, (temp drop, LTI drop) or None)."""
+        with TraceAnnotation("fdann.search.prepare",
+                             batch=serving_batch.get()) as span:
+            lanes = self._capture_lanes()
+            rw_t, ro_temps, lti_entry = lanes
+            span.set_metadata(lanes=(rw_t is not None) + len(ro_temps)
+                              + (lti_entry is not None))
+            if not self.cfg.batch_fanout or (
+                    rw_t is None and not ro_temps and lti_entry is None):
+                return lanes, None, None
+            bundle = self._lane_bundle(rw_t, ro_temps, lti_entry)
+            if bundle is None:
+                return lanes, None, None
+            key, _, _, _, tables_np, label_tabs = bundle
+            drop = (self._drop_mask(key, tables_np) if fspec is None else
+                    self._filter_drop(key, tables_np, label_tabs, fspec))
+            return lanes, bundle, drop
 
     @staticmethod
     def _slot_filter(slot_ids: np.ndarray, dists: np.ndarray,
@@ -983,8 +1031,9 @@ class FreshDiskANN:
                            constant_values=np.inf)
         return res_i.astype(np.int64), res_d.astype(np.float32)
 
-    def _flush_inserts(self) -> None:
-        """Land the insert buffer in the RW tier.
+    def _flush_inserts(self) -> int:
+        """Land the insert buffer in the RW tier; returns the points landed
+        (0 when the buffer was empty).
 
         Locking: the buffer swap is the only step under ``_insert_lock``;
         the device-side compute + publish run under ``_flush_lock`` alone
@@ -1002,7 +1051,7 @@ class FreshDiskANN:
         this flush publishes (tests/test_system.py pins it).
         """
         if not self._insert_buf_id:
-            return
+            return 0
         with self._flush_lock:
             with self._insert_lock:
                 ids = self._insert_buf_id
@@ -1010,13 +1059,16 @@ class FreshDiskANN:
                 bits = self._insert_buf_bits
                 tens = self._insert_buf_tenant
                 if not ids:
-                    return
+                    return 0
                 self._insert_buf_id, self._insert_buf_v = [], []
                 self._insert_buf_bits, self._insert_buf_tenant = [], []
             t0 = time.perf_counter()
-            self._flush_compute(ids, vecs, bits, tens)
+            with TraceAnnotation("fdann.flush", points=len(ids),
+                                 chunks=-(-len(ids) // self.cfg.insert_batch)):
+                self._flush_compute(ids, vecs, bits, tens)
             self.stats.flushes += 1
             self.stats.flush_latency.record(time.perf_counter() - t0)
+        return len(ids)
 
     def _flush_compute(self, ids: list, vecs: list, bits: list,
                        tens: list) -> None:
@@ -1142,12 +1194,14 @@ class FreshDiskANN:
         if background:
             if self._merge_thread and self._merge_thread.is_alive():
                 return
-            self._merge_thread = threading.Thread(target=self._merge_worker)
+            self._merge_thread = threading.Thread(target=self._merge_worker,
+                                                  name="fdann-merge")
             self._merge_thread.start()
         else:
             self._merge_impl()
 
     def _merge_worker(self) -> None:
+        name_os_thread("fdann-merge")
         try:
             self._merge_impl()
         except BaseException as e:
@@ -1162,7 +1216,8 @@ class FreshDiskANN:
             raise err
 
     def _merge_impl(self) -> None:
-        with self._merge_lock:
+        with self._merge_lock, TraceAnnotation(
+                "fdann.merge", merge=self.stats.merges) as span:
             t0 = time.perf_counter()
             # Snapshot the RO list but KEEP it searchable while the merge
             # runs: its points leave self.ro only after the new LTI (which
@@ -1173,107 +1228,121 @@ class FreshDiskANN:
             with self._ro_lock:
                 ro = list(self.ro)
                 self._merge_inflight = sum(t.n for t in ro)
+            span.set_metadata(staged=self._merge_inflight)
             try:
-                self._merge_body(ro, t0)
+                deletes, repair_mode = self._merge_body(ro, t0)
+                span.set_metadata(deletes=deletes, repair_mode=repair_mode)
             finally:
                 # A failed merge must not leave the in-flight count set, or
                 # every future threshold check would under-count and no
                 # merge would ever run again.
                 self._merge_inflight = 0
 
-    def _merge_body(self, ro: list, t0: float) -> None:
+    def _merge_body(self, ro: list, t0: float) -> tuple[int, str]:
+        """One StreamingMerge of the snapshotted RO tiers ``ro``; returns
+        the DeleteList size it consumed against and its Delete phase's
+        repair mode, for the ``fdann.merge`` span."""
         staged = sum(t.n for t in ro)
         icfg = self.cfg.index
-        # The pre-merge adjacency anchors the delta patch: the live layout
-        # is in sync with it, so rows that survive the merge unchanged need
-        # no disk write (storage.layout.patch_layout).
-        old_adj = self.lti.graph.adjacency if self.cfg.storage_dir else None
-        # Stage vectors + ids from the RO snapshots (skip re-deleted ones).
-        del_snapshot = set(self.deleted_ext)
-        vecs = np.zeros((max(staged, 1), icfg.dim), np.float32)
-        exts = np.full(max(staged, 1), -1, np.int64)
-        sbits = np.zeros((max(staged, 1), self._n_label_words), np.uint32)
-        sten = np.full(max(staged, 1), NO_TENANT, np.int32)
-        w = 0
-        for t in ro:
-            sl = np.nonzero(t.ext_ids >= 0)[0][:t.n]
-            v = np.asarray(t.state.vectors)[sl]
-            for s, row in zip(sl, v):
-                e = int(t.ext_ids[s])
-                if e in del_snapshot:
-                    continue
-                vecs[w], exts[w] = row, e
-                if t.labels is not None:   # labels follow the point
-                    sbits[w] = t.labels.bits[s]
-                    sten[w] = t.labels.tenant[s]
-                w += 1
-        valid = np.zeros(max(staged, 1), bool)
-        valid[:w] = True
-        # Remove from the LTI: DeleteList members AND rows superseded by a
-        # staged re-insert — after delete(e) + insert(e, v2), e's old LTI
-        # row still holds the pre-delete vector; without this it would
-        # survive the merge as a stale duplicate and searches could return
-        # e ranked by the OLD vector.
-        dmask = np.zeros(icfg.capacity, bool)
-        lti_ids = self.lti_ext_ids
-        if del_snapshot:
-            dl = np.asarray(sorted(del_snapshot), np.int64)
-            dmask[np.isin(lti_ids, dl)] = True
-        if w:
-            dmask[np.isin(lti_ids, exts[:w])] = True
-        repair_mode = self._pick_repair_mode(dmask)
-        new_lti, stats = streaming_merge(
-            self.lti, jnp.asarray(vecs), jnp.asarray(valid),
-            jnp.asarray(dmask), icfg, self.cfg.pq,
-            insert_chunk=self.cfg.insert_batch, block=self.cfg.merge_block,
-            repair_mode=repair_mode,
-            # Locality merge (docs/ARCHITECTURE.md, "Update-path
-            # locality"): seeded by the merge ordinal so every merge is
-            # deterministic for its inputs yet successive merges don't
-            # reuse one medoid sample.
-            locality=self.cfg.locality_order,
-            locality_seed=self.stats.merges)
-        jax.block_until_ready(new_lti.graph.adjacency)
-        self.stats.repair_cap_overflows += int(stats.repair_cap_overflows)
-        self.stats.merge_backedge_targets += int(stats.n_backedge_targets)
-        self.stats.merge_prune_rows += int(stats.n_prune_rows)
-        if repair_mode == "local":
-            self.stats.local_repairs += 1
-        else:
-            self.stats.global_repairs += 1
-            self._force_global_repair = False  # the escalation is served
-        # Rebuild the external-id table: deleted rows out, new rows in
-        # (the merge reports the slot it assigned to each staged row).
-        new_ids = self.lti_ext_ids.copy()
-        for e in new_ids[dmask]:
-            e = int(e)
-            if e >= 0 and self._ext_loc.get(e, ("?",))[0] == "lti":
-                del self._ext_loc[e]     # removed from the LTI this cycle
-        new_ids[dmask] = -1
-        # Labels follow the same deleted-rows-out / staged-rows-in rebuild
-        # as the ext-id table, scattered at the merge-assigned slots.
-        new_labels = self.lti_labels.copy()
-        new_labels.clear_rows(dmask)
-        slots = np.asarray(stats.slots)
-        ok = valid & (slots >= 0)
-        for i, (s, e) in zip(np.nonzero(ok)[0], zip(slots[ok], exts[ok])):
-            new_ids[s] = e
-            new_labels.bits[s] = sbits[i]
-            new_labels.tenant[s] = sten[i]
-            self._ext_loc[e] = ("lti", int(s))
-        # One-shot generation swap (graph + ext table + labels together),
-        # then retire exactly the RO snapshots this merge consumed —
-        # anything appended by a concurrent rollover stays.
-        self._lti_pair = (new_lti, new_ids, new_labels)
-        with self._ro_lock:
-            self.ro = self.ro[len(ro):]
-            self._merge_inflight = 0
-        self._tuned_w = None       # the graph changed: re-calibrate W
-        self._fanout_cache = None  # retired RO stacks must not stay resident
-        self._frozen_cache = None
-        self._drop_cache = None
-        self._filter_cache = None
-        self._shard_place = None   # the old LTI's sharded copy likewise
+        merge = self.stats.merges       # every phase span carries it
+        with TraceAnnotation("fdann.merge.stage", merge=merge):
+            # The pre-merge adjacency anchors the delta patch: the live
+            # layout is in sync with it, so rows that survive the merge
+            # unchanged need no disk write (storage.layout.patch_layout).
+            old_adj = (self.lti.graph.adjacency if self.cfg.storage_dir
+                       else None)
+            # Stage vectors + ids from the RO snapshots (skip re-deleted).
+            del_snapshot = set(self.deleted_ext)
+            vecs = np.zeros((max(staged, 1), icfg.dim), np.float32)
+            exts = np.full(max(staged, 1), -1, np.int64)
+            sbits = np.zeros((max(staged, 1), self._n_label_words),
+                             np.uint32)
+            sten = np.full(max(staged, 1), NO_TENANT, np.int32)
+            w = 0
+            for t in ro:
+                sl = np.nonzero(t.ext_ids >= 0)[0][:t.n]
+                v = np.asarray(t.state.vectors)[sl]
+                for s, row in zip(sl, v):
+                    e = int(t.ext_ids[s])
+                    if e in del_snapshot:
+                        continue
+                    vecs[w], exts[w] = row, e
+                    if t.labels is not None:   # labels follow the point
+                        sbits[w] = t.labels.bits[s]
+                        sten[w] = t.labels.tenant[s]
+                    w += 1
+            valid = np.zeros(max(staged, 1), bool)
+            valid[:w] = True
+            # Remove from the LTI: DeleteList members AND rows superseded by
+            # a staged re-insert — after delete(e) + insert(e, v2), e's old
+            # LTI row still holds the pre-delete vector; without this it
+            # would survive the merge as a stale duplicate and searches
+            # could return e ranked by the OLD vector.
+            dmask = np.zeros(icfg.capacity, bool)
+            lti_ids = self.lti_ext_ids
+            if del_snapshot:
+                dl = np.asarray(sorted(del_snapshot), np.int64)
+                dmask[np.isin(lti_ids, dl)] = True
+            if w:
+                dmask[np.isin(lti_ids, exts[:w])] = True
+            repair_mode = self._pick_repair_mode(dmask)
+        with TraceAnnotation("fdann.merge.launch", merge=merge):
+            new_lti, stats = streaming_merge(
+                self.lti, jnp.asarray(vecs), jnp.asarray(valid),
+                jnp.asarray(dmask), icfg, self.cfg.pq,
+                insert_chunk=self.cfg.insert_batch,
+                block=self.cfg.merge_block, repair_mode=repair_mode,
+                # Locality merge (docs/ARCHITECTURE.md, "Update-path
+                # locality"): seeded by the merge ordinal so every merge is
+                # deterministic for its inputs yet successive merges don't
+                # reuse one medoid sample.
+                locality=self.cfg.locality_order, locality_seed=merge)
+        with TraceAnnotation("fdann.merge.wait", merge=merge):
+            jax.block_until_ready(new_lti.graph.adjacency)
+        with TraceAnnotation("fdann.merge.publish", merge=merge):
+            self.stats.repair_cap_overflows += int(
+                stats.repair_cap_overflows)
+            self.stats.merge_backedge_targets += int(stats.n_backedge_targets)
+            self.stats.merge_prune_rows += int(stats.n_prune_rows)
+            if repair_mode == "local":
+                self.stats.local_repairs += 1
+            else:
+                self.stats.global_repairs += 1
+                self._force_global_repair = False  # the escalation is served
+            # Rebuild the external-id table: deleted rows out, new rows in
+            # (the merge reports the slot it assigned to each staged row).
+            new_ids = self.lti_ext_ids.copy()
+            for e in new_ids[dmask]:
+                e = int(e)
+                if e >= 0 and self._ext_loc.get(e, ("?",))[0] == "lti":
+                    del self._ext_loc[e]     # removed from the LTI this cycle
+            new_ids[dmask] = -1
+            # Labels follow the same deleted-rows-out / staged-rows-in
+            # rebuild as the ext-id table, scattered at the merge-assigned
+            # slots.
+            new_labels = self.lti_labels.copy()
+            new_labels.clear_rows(dmask)
+            slots = np.asarray(stats.slots)
+            ok = valid & (slots >= 0)
+            for i, (s, e) in zip(np.nonzero(ok)[0],
+                                 zip(slots[ok], exts[ok])):
+                new_ids[s] = e
+                new_labels.bits[s] = sbits[i]
+                new_labels.tenant[s] = sten[i]
+                self._ext_loc[e] = ("lti", int(s))
+            # One-shot generation swap (graph + ext table + labels
+            # together), then retire exactly the RO snapshots this merge
+            # consumed — anything appended by a concurrent rollover stays.
+            self._lti_pair = (new_lti, new_ids, new_labels)
+            with self._ro_lock:
+                self.ro = self.ro[len(ro):]
+                self._merge_inflight = 0
+            self._tuned_w = None       # the graph changed: re-calibrate W
+            self._fanout_cache = None  # retired RO stacks must not stay
+            self._frozen_cache = None  # resident
+            self._drop_cache = None
+            self._filter_cache = None
+            self._shard_place = None   # the old LTI's sharded copy likewise
         if self.cfg.storage_dir:
             # Delta-patch the live layout: only the adjacency rows this
             # merge rewrote touch topology.bin; surviving points' vector
@@ -1283,16 +1352,18 @@ class FreshDiskANN:
             self._sync_storage(
                 adj_changed=np.asarray(adjacency_delta_mask(
                     old_adj, new_lti.graph.adjacency)))
-        # A delete may leave the DeleteList only when NO copy of the id
-        # survives the merge anywhere — LTI residents left via the dmask
-        # pass and merged-RO residents were skipped at staging, but a
-        # delete of a point still living in the RW tier (or an RO
-        # snapshot that rolled over after this merge began, or the
-        # insert buffer) must SURVIVE, or the live copy would be revived.
-        alive = self._live_ext_ids()
-        dl = np.fromiter(del_snapshot, np.int64, len(del_snapshot))
-        self.deleted_ext -= set(dl[~np.isin(dl, alive)].tolist())
-        self._delete_epoch += 1
+        with TraceAnnotation("fdann.merge.retire", merge=merge):
+            # A delete may leave the DeleteList only when NO copy of the id
+            # survives the merge anywhere — LTI residents left via the dmask
+            # pass and merged-RO residents were skipped at staging, but a
+            # delete of a point still living in the RW tier (or an RO
+            # snapshot that rolled over after this merge began, or the
+            # insert buffer) must SURVIVE, or the live copy would be
+            # revived.
+            alive = self._live_ext_ids()
+            dl = np.fromiter(del_snapshot, np.int64, len(del_snapshot))
+            self.deleted_ext -= set(dl[~np.isin(dl, alive)].tolist())
+            self._delete_epoch += 1
         if self.wal:
             if self.cfg.snapshot_dir:
                 # Durability invariant (§5.6): snapshot BEFORE truncate, so
@@ -1314,7 +1385,9 @@ class FreshDiskANN:
             # pre-merge records, truncating would lose them on crash.
         self.stats.merges += 1
         self.stats.merge_seconds += time.perf_counter() - t0
-        self._probe_reachability(repair_mode)
+        with TraceAnnotation("fdann.merge.probe", merge=merge):
+            self._probe_reachability(repair_mode)
+        return len(del_snapshot), repair_mode
 
     def _pick_repair_mode(self, dmask: np.ndarray) -> str:
         """Route the merge's Delete phase: the localized affected-set sweep

@@ -34,6 +34,9 @@ from typing import Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
+
+from ..core.system import lane_hops_metadata, serving_batch
 
 
 class ReplicaSet:
@@ -85,7 +88,7 @@ class ReplicaSet:
         dispatched to a replica: round-robin by default, or pinned with
         ``replica=r``."""
         sys_ = self.system
-        sys_._flush_inserts()
+        sys_._search_flush()
         fspec = filter if filter is not None and not filter.is_empty \
             else None
         L = L or sys_.cfg.index.L_search
@@ -138,39 +141,37 @@ class ReplicaSet:
         """Serve ONE fixed-shape micro-batch on one replica's device group.
 
         Mirrors ``system._search_dispatch``: same lane capture, same
-        bundle, same drop masks, same per-dispatch latency sample — only
-        the mesh the sharded program runs on differs.  Falls back to the
-        system's own dispatch when there is no LTI lane to place (the
-        bundle-less warm-up regime, or ``batch_fanout=False``): the
-        replica axis only exists once an LTI generation is live."""
+        bundle, same drop masks, same spans, and the same per-dispatch
+        latency sample, timed from the lane capture to the results on the
+        host — only the mesh the sharded program runs on differs.  Falls
+        back to the system's own dispatch when there is no LTI lane to
+        place (the bundle-less warm-up regime, or ``batch_fanout=False``):
+        the replica axis only exists once an LTI generation is live."""
         import jax.numpy as jnp
         sys_ = self.system
         r = replica if replica is not None else self._next_replica()
         if not 0 <= r < self.n_replicas:
             raise ValueError(f"replica={r} out of range "
                              f"[0, {self.n_replicas})")
-        rw_t, ro_temps, lti_entry = sys_._capture_lanes()
+        t0 = time.perf_counter()
+        (rw_t, ro_temps, lti_entry), bundle, drop = sys_._prepare_batch(fspec)
         if rw_t is None and not ro_temps and lti_entry is None:
             return sys_._aggregate([], k, queries.shape[0])
-        bundle = (sys_._lane_bundle(rw_t, ro_temps, lti_entry)
-                  if sys_.cfg.batch_fanout else None)
         if bundle is None or lti_entry is None:
             self.dispatches[r] += 1     # routed, served on the system path
             return sys_._search_dispatch(queries, k, kk, L, W, fspec)
-        key, stack, t_tabs, l_tab, tables_np, label_tabs = bundle
-        if fspec is None:
-            t_drop, l_drop = sys_._drop_mask(key, tables_np)
-        else:
-            t_drop, l_drop = sys_._filter_drop(key, tables_np, label_tabs,
-                                               fspec)
-        do_rerank = sys_.cfg.rerank
-        step, sstack = self._replica_program(
-            r, stack, k=k, kk=kk, L=L, W=W, rerank=do_rerank)
-        t0 = time.perf_counter()
-        ids, d, _, _ = step(sstack, t_tabs, l_tab, t_drop, l_drop,
-                            jnp.asarray(queries, jnp.float32))
-        out = (np.asarray(ids).astype(np.int64),
-               np.asarray(d).astype(np.float32))
+        _, stack, t_tabs, l_tab, _, _ = bundle
+        t_drop, l_drop = drop
+        with TraceAnnotation("fdann.search.device",
+                             batch=serving_batch.get()) as span:
+            step, sstack = self._replica_program(
+                r, stack, k=k, kk=kk, L=L, W=W, rerank=sys_.cfg.rerank)
+            ids, d, hops, _ = step(sstack, t_tabs, l_tab, t_drop, l_drop,
+                                   jnp.asarray(queries, jnp.float32))
+            out = (np.asarray(ids).astype(np.int64),
+                   np.asarray(d).astype(np.float32))
+            if span.is_enabled():
+                lane_hops_metadata(span, hops)
         sys_.stats.search_latency.record(time.perf_counter() - t0)
         sys_.stats.search_dispatches += 1
         self.dispatches[r] += 1

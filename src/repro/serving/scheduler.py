@@ -50,6 +50,9 @@ from collections import deque
 from typing import Callable, Optional, Protocol, runtime_checkable
 
 import numpy as np
+from jax.profiler import TraceAnnotation
+
+from ..core.system import name_os_thread, serving_batch
 
 
 @runtime_checkable
@@ -318,8 +321,17 @@ class BatchScheduler:
             # Filter rides as a kwarg only when set, so label-free serve
             # callables (and pre-filter test doubles) keep their signature.
             kw["filter"] = batch[0].fspec
-        ids, dists = self._serve(qs, self.k, L=self.L,
-                                 beam_width=self.beam_width, **kw)
+        # The batch sequence number rides the serve call: every search span
+        # under it carries it (docs/SERVING.md, "Tracing").
+        token = serving_batch.set(self._batches)
+        try:
+            with TraceAnnotation("fdann.serve.batch", batch=self._batches,
+                                 n=len(batch), oldest_wait_ms=(
+                                     t0 - batch[0].arrival) * 1e3):
+                ids, dists = self._serve(qs, self.k, L=self.L,
+                                         beam_width=self.beam_width, **kw)
+        finally:
+            serving_batch.reset(token)
         t1 = self.clock.now()
         # EWMA toward the measured dispatch; on a virtual clock the
         # measurement is the test's advance (0 unless it models compute),
@@ -371,7 +383,8 @@ class BatchScheduler:
         if self._thread and self._thread.is_alive():
             return
         self._running = True
-        self._thread = threading.Thread(target=self._loop, daemon=True)
+        self._thread = threading.Thread(target=self._loop, daemon=True,
+                                        name="fdann-serve")
         self._thread.start()
 
     def stop(self, flush: bool = True) -> None:
@@ -393,6 +406,7 @@ class BatchScheduler:
             self.flush()
 
     def _loop(self) -> None:
+        name_os_thread("fdann-serve")
         while True:
             with self._cond:
                 if not self._running:
