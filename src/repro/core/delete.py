@@ -31,6 +31,15 @@ Two sweep modes (``IndexConfig.repair_mode``, overridable per call):
   materialized on the host (data-dependent size), so the localized mode
   cannot run under an enclosing ``jit`` — ``streaming_merge`` dispatches
   around it.
+
+The localized sweep runs as bounded device slices (``SliceRunner``): the
+affected-set scan over fixed ranges of ``RANGE_ROWS`` rows, one repair
+program per block of affected rows, the reclamation, and one small write
+per block, each launched only after the one before it has finished.  A
+TPU cannot preempt a running program, so a search that arrives meanwhile
+waits out one slice, not the whole sweep.  Every slice has a fixed shape,
+so the sweep compiles the same programs whatever the number of affected
+rows.
 """
 from __future__ import annotations
 
@@ -66,7 +75,8 @@ def affected_mask(adjacency: jax.Array, deleted: jax.Array,
     scan because an edge p->v with v in D *is* p being an in-neighbor of
     D.  Rows outside the mask are untouched by the repair (their
     ``nbr_del.any()`` select keeps the old row), so repairing only the
-    masked rows is bit-identical to the global sweep.
+    masked rows is bit-identical to the global sweep.  ``adjacency`` and
+    ``usable`` may be any range of rows; ``deleted`` is the whole mask.
     """
     safe = jnp.maximum(adjacency, 0)
     nbr_del = (adjacency >= 0) & deleted[safe]
@@ -85,6 +95,92 @@ def repair_cap_overflow(adjacency: jax.Array, deleted: jax.Array,
     return jnp.sum((jnp.sum(nbr_del, axis=1) > cap) & usable)
 
 
+# Rows per slice of the localized sweep's passes over every row (the
+# affected-set scan here, the merge's decode of its prune table): each such
+# pass over a 2^21-row shard takes 1-2 s on a v5e, so 2^16 rows keep a
+# slice well under one repair block's ~0.25 s.
+RANGE_ROWS = 1 << 16
+
+
+class SliceRunner:
+    """Runs device programs one at a time and counts them.
+
+    Each call launches one jitted program and waits for it to finish
+    before returning, so at most one of the caller's programs sits in the
+    device queue: a program another thread enqueues meanwhile (a search)
+    runs after the current slice, not after all of them."""
+
+    def __init__(self):
+        self.count = 0
+
+    def __call__(self, program, *args, **kwargs):
+        out = jax.block_until_ready(program(*args, **kwargs))
+        self.count += 1
+        return out
+
+
+def row_ranges(n: int) -> tuple[list, int]:
+    """(start rows, rows per slice) of the ``RANGE_ROWS``-row slices that
+    cover ``n`` rows.  The last start is pulled back so every slice has
+    one shape; rows covered twice are computed twice, identically."""
+    rows = min(RANGE_ROWS, n)
+    return [min(lo, n - rows) for lo in range(0, n, rows)], rows
+
+
+@partial(jax.jit, static_argnames=("rows", "cap"))
+def _affected_range(adjacency, deleted, usable, lo, first, *, rows, cap):
+    """``affected_mask`` of rows [lo, lo + rows), and (``cap`` set) how
+    many of those rows at or past ``first`` overflow the SDC cap."""
+    adj = jax.lax.dynamic_slice_in_dim(adjacency, lo, rows)
+    use = jax.lax.dynamic_slice_in_dim(usable, lo, rows)
+    mask = affected_mask(adj, deleted, use)
+    if cap is None:
+        return mask, jnp.int32(0)
+    fresh = lo + jnp.arange(rows) >= first
+    return mask, repair_cap_overflow(adj, deleted, use & fresh, cap)
+
+
+def affected_rows(adjacency, deleted, usable, run: SliceRunner,
+                  cap: int | None = None) -> tuple[np.ndarray, int]:
+    """The affected set's ids (ascending, on the host) and, with ``cap``,
+    the ``repair_cap_overflow`` count, one ``RANGE_ROWS`` slice at a
+    time."""
+    n = adjacency.shape[0]
+    starts, rows = row_ranges(n)
+    mask = np.zeros(n, bool)
+    over = 0
+    for i, lo in enumerate(starts):
+        part, n_over = run(_affected_range, adjacency, deleted, usable, lo,
+                           i * rows, rows=rows, cap=cap)
+        mask[lo:lo + rows] = np.asarray(part)
+        over += int(n_over)
+    return np.nonzero(mask)[0], over
+
+
+def repair_sliced(state: GraphState, aff: np.ndarray, block: int,
+                  repair_slice, run: SliceRunner) -> GraphState:
+    """Localized sweep body: repair the affected ids ``aff`` one block per
+    slice — ``repair_slice(ids)`` returns block ``ids``'s repaired rows,
+    read against the pre-repair ``state.adjacency`` — then reclaim
+    ``state.deleted`` and write each block's rows into the reclaimed
+    adjacency.  Reclaiming after the repair keeps the reclaimed copy out
+    of the repair slices' memory.  The last block is padded with
+    ``aff[0]``: a duplicate id writes the same repaired row again."""
+    repaired = []
+    if len(aff):
+        n_blocks = -(-len(aff) // block)
+        padded = np.full(n_blocks * block, aff[0], dtype=np.int32)
+        padded[:len(aff)] = aff
+        for ids in padded.reshape(n_blocks, block):
+            ids = jnp.asarray(ids)
+            repaired.append((ids, run(repair_slice, ids)))
+    adjacency, active, deleted, start = run(_reclaim, state)
+    for ids, rows in repaired:
+        adjacency = run(_write_rows, adjacency, ids, rows)
+    return state._replace(adjacency=adjacency, active=active,
+                          deleted=deleted, start=start)
+
+
 def _finish_consolidate(state: GraphState, adjacency: jax.Array) -> GraphState:
     """Shared consolidation tail: slot reclamation + entry-point upkeep.
 
@@ -92,6 +188,8 @@ def _finish_consolidate(state: GraphState, adjacency: jax.Array) -> GraphState:
     already the empty sentinel; when NO live point remains the start
     becomes INVALID (searches then return empty instead of seeding from a
     garbage medoid of an all-false mask) and the next insert re-seeds it.
+    Repaired rows are live, so reclamation may run before or after the
+    repair writes them.
     """
     adjacency = jnp.where(state.deleted[:, None], INVALID, adjacency)
     active = state.active & ~state.deleted
@@ -106,13 +204,37 @@ def _finish_consolidate(state: GraphState, adjacency: jax.Array) -> GraphState:
         deleted=jnp.zeros_like(state.deleted), start=start)
 
 
+@jax.jit
+def _reclaim(state: GraphState):
+    """``_finish_consolidate`` of ``state``'s own adjacency as one program,
+    returning only the fields it rewrites (adjacency, active, deleted,
+    start): a field a jit returns unchanged is copied."""
+    out = _finish_consolidate(state, state.adjacency)
+    return out.adjacency, out.active, out.deleted, out.start
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _write_rows(adjacency, ids, rows):
+    """Write one block of repaired rows into ``adjacency`` (donated, so
+    the blocks share one copy)."""
+    return adjacency.at[ids].set(rows)
+
+
+def _consolidate_local(state: GraphState, usable, block: int,
+                       repair_slice) -> GraphState:
+    """The localized sweep as slices: the affected-set ranges, then
+    ``repair_sliced``."""
+    run = SliceRunner()
+    aff, _ = affected_rows(state.adjacency, state.deleted, usable, run)
+    return repair_sliced(state, aff, block, repair_slice, run)
+
+
 def _scatter_repaired(adjacency, rows_fn, aff: np.ndarray, block: int, R):
-    """Localized sweep body: pad the affected ids to a block multiple,
-    repair block-by-block with the SAME engine as the global sweep
-    (``rows_fn`` maps [n_blocks, block] ids -> repaired rows), and
-    scatter the rows back.  Padding duplicates ``aff[0]`` — duplicate
-    scatter indices write identical repaired rows, so the result is
-    well-defined."""
+    """One-program form of the localized scatter (``repair_sliced`` is the
+    sweep's own): pad the affected ids to a block multiple, repair them
+    all (``rows_fn`` maps [n_blocks, block] ids -> repaired rows), and
+    scatter the rows back.  The benchmark's warm-up
+    (``bench/harness/window.py``) calls it."""
     n_blocks = -(-len(aff) // block)
     padded = np.full(n_blocks * block, aff[0], dtype=np.int32)
     padded[:len(aff)] = aff
@@ -168,17 +290,25 @@ def _repair_block_kernel(adjacency, prune_table, deleted, usable, node_ids,
 @partial(jax.jit, static_argnames=("alpha", "R", "kernel"))
 def _repair_blocks_fp(adjacency, table, deleted, usable, ids, alpha, R,
                       kernel):
-    """Blocked full-precision repair sweep, jitted ONCE per (shape,
-    alpha, R, engine) so repeated consolidations — standalone calls, the
-    localized merge path — reuse the compiled program instead of paying a
-    prune-engine retrace per call.  Nested jit inlines, so the fused
-    merge program is unchanged."""
+    """Blocked full-precision repair sweep over [n_blocks, block] ids,
+    jitted ONCE per (shape, alpha, R, engine) so repeated global
+    consolidations reuse the compiled program.  Nested jit inlines, so the
+    fused merge program is unchanged."""
     repair = _repair_block_kernel if kernel else _repair_block
 
     def run(b):
         return repair(adjacency, table, deleted, usable, b, alpha, R)
 
     return jax.lax.map(run, ids)
+
+
+@partial(jax.jit, static_argnames=("alpha", "R", "kernel"))
+def _repair_slice_fp(adjacency, table, deleted, usable, ids, alpha, R,
+                     kernel):
+    """One slice of the localized full-precision sweep: the repaired rows
+    of the block ``ids``."""
+    repair = _repair_block_kernel if kernel else _repair_block
+    return repair(adjacency, table, deleted, usable, ids, alpha, R)
 
 
 def consolidate_deletes(state: GraphState, cfg: IndexConfig,
@@ -199,25 +329,20 @@ def consolidate_deletes(state: GraphState, cfg: IndexConfig,
     table = state.vectors if prune_table is None else prune_table
     usable = state.active & ~state.deleted
 
-    def rows_fn(ids):
-        return _repair_blocks_fp(state.adjacency, table, state.deleted,
-                                 usable, ids, cfg.alpha, cfg.R,
-                                 cfg.kernel_enabled())
-
     if (cfg.repair_mode if mode is None else mode) == "local":
-        aff = np.nonzero(np.asarray(
-            affected_mask(state.adjacency, state.deleted, usable)))[0]
-        adjacency = (state.adjacency if len(aff) == 0 else
-                     _scatter_repaired(state.adjacency, rows_fn, aff,
-                                       block, cfg.R))
-    else:
-        n_blocks = -(-N // block)
-        pad = n_blocks * block
-        ids = jnp.arange(pad, dtype=jnp.int32).clip(0, N - 1).reshape(
-            n_blocks, block)
-        adjacency = rows_fn(ids).reshape(pad, cfg.R)[:N]
+        return _consolidate_local(
+            state, usable, block, lambda ids: _repair_slice_fp(
+                state.adjacency, table, state.deleted, usable, ids,
+                cfg.alpha, cfg.R, cfg.kernel_enabled()))
+    n_blocks = -(-N // block)
+    pad = n_blocks * block
+    ids = jnp.arange(pad, dtype=jnp.int32).clip(0, N - 1).reshape(
+        n_blocks, block)
+    adjacency = _repair_blocks_fp(state.adjacency, table, state.deleted,
+                                  usable, ids, cfg.alpha, cfg.R,
+                                  cfg.kernel_enabled())
     # Reclaim: deleted slots become free (edges cleared, flags reset).
-    return _finish_consolidate(state, adjacency)
+    return _finish_consolidate(state, adjacency.reshape(pad, cfg.R)[:N])
 
 
 def _repair_block_codes(adjacency, codes, tables, deleted, usable, node_ids,
@@ -287,6 +412,16 @@ def _repair_blocks_codes(adjacency, codes, tables, deleted, usable, ids,
     return jax.lax.map(run, ids)
 
 
+@partial(jax.jit, static_argnames=("alpha", "R", "cap", "kernel"))
+def _repair_slice_codes(adjacency, codes, tables, deleted, usable, ids,
+                        alpha, R, cap, kernel):
+    """SDC twin of ``_repair_slice_fp``."""
+    repair = (_repair_block_codes_kernel if kernel
+              else _repair_block_codes)
+    return repair(adjacency, codes, tables, deleted, usable, ids, alpha, R,
+                  cap)
+
+
 def consolidate_deletes_codes(state: GraphState, cfg: IndexConfig,
                               codes: jax.Array, tables: jax.Array,
                               block: int = 1024,
@@ -297,24 +432,19 @@ def consolidate_deletes_codes(state: GraphState, cfg: IndexConfig,
     N = state.capacity
     usable = state.active & ~state.deleted
 
-    def rows_fn(ids):
-        return _repair_blocks_codes(state.adjacency, codes, tables,
-                                    state.deleted, usable, ids, cfg.alpha,
-                                    cfg.R, cap, cfg.kernel_enabled())
-
     if (cfg.repair_mode if mode is None else mode) == "local":
-        aff = np.nonzero(np.asarray(
-            affected_mask(state.adjacency, state.deleted, usable)))[0]
-        adjacency = (state.adjacency if len(aff) == 0 else
-                     _scatter_repaired(state.adjacency, rows_fn, aff,
-                                       block, cfg.R))
-    else:
-        n_blocks = -(-N // block)
-        pad = n_blocks * block
-        ids = jnp.arange(pad, dtype=jnp.int32).clip(0, N - 1).reshape(
-            n_blocks, block)
-        adjacency = rows_fn(ids).reshape(pad, cfg.R)[:N]
-    return _finish_consolidate(state, adjacency)
+        return _consolidate_local(
+            state, usable, block, lambda ids: _repair_slice_codes(
+                state.adjacency, codes, tables, state.deleted, usable, ids,
+                cfg.alpha, cfg.R, cap, cfg.kernel_enabled()))
+    n_blocks = -(-N // block)
+    pad = n_blocks * block
+    ids = jnp.arange(pad, dtype=jnp.int32).clip(0, N - 1).reshape(
+        n_blocks, block)
+    adjacency = _repair_blocks_codes(state.adjacency, codes, tables,
+                                     state.deleted, usable, ids, cfg.alpha,
+                                     cfg.R, cap, cfg.kernel_enabled())
+    return _finish_consolidate(state, adjacency.reshape(pad, cfg.R)[:N])
 
 
 # ----------------------------------------------------------------------------

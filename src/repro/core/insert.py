@@ -121,6 +121,56 @@ def _dedupe_combine(combine: jax.Array) -> jax.Array:
     return jnp.where(dup, INVALID, combine)
 
 
+def patch_plan(pairs_j: jax.Array, pairs_p: jax.Array, n_slots: int, *,
+               d_max: int, chunk: int, affected_cap: int | None = None
+               ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Delta grouped for the Patch phase's block pass: the affected targets
+    as [n_blocks, block] ids (padded with ``n_slots``), each target's Delta
+    sources [n_blocks, block, d_max], and whether it has any.
+
+    Every affected node appears (<= P of them); top_k over the 0/1
+    indicator returns lowest-index ties first, so all 1s are captured when
+    P <= a_max.  ``affected_cap`` (static) bounds the rows below the worst
+    case min(P, N).  The locality paths measure the DISTINCT back-edge
+    target count D on the host and pass a power-of-two bucket >= D
+    (``locality.next_bucket``), so a proximity-ordered batch whose pairs
+    collide onto few targets launches a proportionally smaller prune — the
+    fixed-shape program cannot shrink dynamically otherwise.  Correctness
+    requires cap >= D.  None = worst case."""
+    buf, cnt = group_pairs(pairs_j, pairs_p, n_slots, d_max)
+    a_max = min(pairs_j.shape[0], n_slots)
+    if affected_cap is not None:
+        a_max = max(1, min(a_max, int(affected_cap)))
+    _, affected = jax.lax.top_k((cnt > 0).astype(jnp.int32), a_max)
+    bs = min(a_max, chunk)
+    n_blocks = -(-a_max // bs)
+    js = jnp.concatenate(
+        [affected.astype(jnp.int32),
+         jnp.full((n_blocks * bs - a_max,), n_slots, jnp.int32)]
+    ).reshape(n_blocks, bs)
+    safe = jnp.minimum(js, n_slots - 1)
+    return js, buf[safe], cnt[safe] > 0
+
+
+def patch_block(adjacency, backend, usable, js, extra, has, *, alpha, R,
+                use_kernel):
+    """One block of the Patch phase: each target in ``js`` with Delta
+    sources (``has``) appends ``extra`` to its row, or re-prunes the union
+    once it passes R (Alg. 2).  Pad ids (>= N) write nothing."""
+    N = adjacency.shape[0]
+    safe = jnp.minimum(js, N - 1)
+    rows = adjacency[safe]
+    combine = _dedupe_combine(jnp.concatenate([rows, extra], axis=1))
+    total = (combine >= 0).sum(axis=1)
+    app_order = jnp.argsort(~(combine >= 0), axis=1)
+    appended = jnp.take_along_axis(combine, app_order, axis=1)[:, :R]
+    pruned = prune_node_batch(backend, safe, combine, usable,
+                              alpha=alpha, R=R, use_kernel=use_kernel).ids
+    new_rows = jnp.where((total > R)[:, None], pruned, appended)
+    new_rows = jnp.where(has[:, None], new_rows, rows)
+    return adjacency.at[jnp.where(js < N, js, N)].set(new_rows, mode="drop")
+
+
 def _apply_back_edges_impl(adjacency, backend, usable, pairs_j, pairs_p, *,
                            alpha, R, d_max, chunk, use_kernel,
                            affected_cap=None):
@@ -128,54 +178,20 @@ def _apply_back_edges_impl(adjacency, backend, usable, pairs_j, pairs_p, *,
 
     Affected nodes are processed in blocks via ``lax.scan`` — the Patch-phase
     block pass of StreamingMerge (one block of rows streamed, patched, written
-    back) and a memory bound for plain batched inserts alike.
-
-    ``affected_cap`` (static) bounds the number of processed rows below the
-    worst case min(P, N).  The locality paths measure the DISTINCT back-edge
-    target count D on the host and pass a power-of-two bucket >= D
-    (``locality.next_bucket``), so a proximity-ordered batch whose pairs
-    collide onto few targets launches a proportionally smaller prune — the
-    fixed-shape program cannot shrink dynamically otherwise.  Correctness
-    requires cap >= D: top_k over the 0/1 indicator captures every affected
-    row exactly when the launch width covers the 1s.  None = worst case
-    (bit-identical to the historical behavior).
+    back) and a memory bound for plain batched inserts alike
+    (``patch_plan``, ``patch_block``).
     """
-    N = adjacency.shape[0]
-    P = pairs_j.shape[0]
-    buf, cnt = group_pairs(pairs_j, pairs_p, N, d_max)
-    # Every affected node appears (<= P of them); top_k over the 0/1 indicator
-    # returns lowest-index ties first, so all 1s are captured when P <= a_max.
-    a_max = min(P, N)
-    if affected_cap is not None:
-        a_max = max(1, min(a_max, int(affected_cap)))
-    _, affected = jax.lax.top_k((cnt > 0).astype(jnp.int32), a_max)
+    js, extra, has = patch_plan(pairs_j, pairs_p, adjacency.shape[0],
+                                d_max=d_max, chunk=chunk,
+                                affected_cap=affected_cap)
 
-    def rows_for(adj, js, usable):
-        rows = adj[js]
-        extra = buf[js]
-        combine = _dedupe_combine(jnp.concatenate([rows, extra], axis=1))
-        total = (combine >= 0).sum(axis=1)
-        app_order = jnp.argsort(~(combine >= 0), axis=1)
-        appended = jnp.take_along_axis(combine, app_order, axis=1)[:, :R]
-        pruned = prune_node_batch(backend, js, combine, usable,
-                                  alpha=alpha, R=R,
-                                  use_kernel=use_kernel).ids
-        new_rows = jnp.where((total > R)[:, None], pruned, appended)
-        return jnp.where((cnt[js] > 0)[:, None], new_rows, rows)
+    def block(adj, blk):
+        return patch_block(adj, backend, usable, *blk, alpha=alpha, R=R,
+                           use_kernel=use_kernel), None
 
-    if a_max <= chunk:
-        rows = rows_for(adjacency, affected, usable)
-        return adjacency.at[affected].set(rows)
-    n_chunks = -(-a_max // chunk)
-    pad = n_chunks * chunk - a_max
-    aff = jnp.concatenate(
-        [affected, jnp.full((pad,), N, jnp.int32)]).reshape(n_chunks, chunk)
-
-    def block(adj, js):
-        rows = rows_for(adj, jnp.minimum(js, N - 1), usable)
-        return adj.at[jnp.where(js < N, js, N)].set(rows, mode="drop"), None
-
-    adjacency, _ = jax.lax.scan(block, adjacency, aff)
+    if js.shape[0] == 1:
+        return block(adjacency, (js[0], extra[0], has[0]))[0]
+    adjacency, _ = jax.lax.scan(block, adjacency, (js, extra, has))
     return adjacency
 
 

@@ -11,34 +11,44 @@ LTI in three phases, all distances from PQ codes.
 Faithfulness notes: every distance below is computed from the PQ codes
 (decoded centroids), never from full-precision vectors — this is what produces
 the paper's small steady-state recall dip (Fig. 4), which our tests reproduce.
-In the paper phases 1/3 are sequential SSD passes; here they are ``lax.map``
-block streams (HBM->VMEM).  Insert-phase searches are vmapped chunks: new
-points have no in-edges until the Patch phase, so chunked execution is
+In the paper phases 1/3 are sequential SSD passes; here they are block
+streams (HBM->VMEM).  Insert-phase searches are vmapped chunks: new points
+have no in-edges until the Patch phase, so chunked execution is
 order-equivalent to the paper's sequential inserts.
 
 All three phases ride the batched mutation engine: the Delete phase repairs
-each block through ``delete.consolidate_deletes{_codes}`` (fused
+each block through the ``delete`` module's repair blocks (fused
 ``delete_repair`` kernel under ``IndexConfig.use_kernel``), the Insert
 phase prunes each chunk with ONE ``prune.robust_prune_batch`` call, and the
-Patch phase applies Delta through ``insert.apply_back_edges{_codes}`` —
-kernel- and jnp-path outputs are bit-identical (docs/ARCHITECTURE.md,
-"Mutation engine").
+Patch phase applies Delta through ``insert.patch_block`` — kernel- and
+jnp-path outputs are bit-identical (docs/ARCHITECTURE.md, "Mutation
+engine").
 
 Delete-phase sweep modes (``repair_mode`` kwarg, None -> ``cfg.repair_mode``):
 
 - ``"global"`` — the whole merge stays ONE jitted device program (the
   historical shape): Algorithm 4 scans every block.
-- ``"local"`` — the Delete phase runs the localized affected-set repair
-  (``delete.consolidate_deletes(mode="local")``), which round-trips the
-  affected ids through the host and therefore runs eagerly; phases 2+3
-  still run as one jitted program (``_insert_patch_phases``, the same
-  traced body the fused path inlines).  Outputs are bit-identical to the
-  global merge — only wall-clock and dispatch count differ.
+- ``"local"`` — the merge runs as bounded device slices, launched one at a
+  time by a ``delete.SliceRunner``, each waited out before the next: a
+  TPU cannot preempt a running program, so a search that arrives during
+  the merge waits out one slice, not the merge.  The slices, in order:
+  one program that marks the DeleteList; the affected-set scan and (full
+  precision) the decode of the prune table, over ranges of
+  ``delete.RANGE_ROWS`` rows; one repair per ``block`` of affected rows;
+  the reclamation of the deleted slots, then one small write of each
+  block's repaired rows; one program that allocates slots and stores the
+  staged rows; one Insert-phase program per ``insert_chunk``; one that
+  groups Delta; one Patch-phase program per ``block`` of targets.  The
+  allocation, insert-chunk and patch-block steps are the same traced
+  functions the fused path runs inside its scans, every slice has a fixed
+  shape (the number of affected rows changes only how many repair and
+  write slices run), and outputs are bit-identical to the global merge.
+  ``MergeStats.slices`` counts the programs.
 """
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -46,14 +56,17 @@ import numpy as np
 
 from . import pq as pqm
 from .config import IndexConfig, PQConfig
-from .delete import (consolidate_deletes, consolidate_deletes_codes,
-                     repair_cap_overflow)
+from .delete import (SliceRunner, _repair_slice_codes, _repair_slice_fp,
+                     affected_rows, consolidate_deletes,
+                     consolidate_deletes_codes, repair_cap_overflow,
+                     repair_sliced, row_ranges)
 from .distance import INVALID
+from .graph import GraphState
 from .insert import (apply_back_edges, apply_back_edges_codes,
-                     compute_insert_edges)
+                     compute_insert_edges, patch_block, patch_plan)
 from .locality import locality_order, next_bucket
 from .lti import LTIState
-from .prune import SDCPrune, robust_prune_batch
+from .prune import FullPrecisionPrune, SDCPrune, robust_prune_batch
 from .search import PQBackend, beam_search
 
 # Expansion cap of the SDC delete repair (candidate width R + cap*R);
@@ -83,6 +96,18 @@ class MergeStats(NamedTuple):
     #   LAUNCHED: the fixed-shape worst case min(P, N) on the arrival-order
     #   paths, the sum of measured power-of-two buckets on the locality
     #   path — the number the locality ordering exists to shrink.
+    slices: int = 1                  # device programs the merge launched
+    #   through its SliceRunner, each waited out before the next; the fused
+    #   merge is one program.
+
+
+class DeletePhase(NamedTuple):
+    """The LTI after the Delete phase, and what the later phases need."""
+    graph: GraphState          # repaired, deleted slots reclaimed
+    n_deleted: jax.Array
+    overflow: jax.Array        # MergeStats.repair_cap_overflows
+    decoded: Optional[jax.Array]   # [capacity, dim] prune table (not use_sdc)
+    tables: Optional[jax.Array]    # SDC tables (use_sdc)
 
 
 def streaming_merge(
@@ -136,75 +161,132 @@ def _streaming_merge_fused(lti, new_vecs, new_valid, delete_mask, cfg, pq_cfg,
                            *, insert_chunk, block, use_sdc):
     """The historical one-program merge: global Delete phase + phases 2/3,
     all inside a single jit."""
-    g = lti.graph
-    codebook = lti.codebook
+    d = _delete_global(lti, delete_mask, cfg, pq_cfg, block=block,
+                       use_sdc=use_sdc)
+    return _insert_patch_phases(
+        d.graph, lti.codes, lti.codebook, d.decoded, new_vecs, new_valid,
+        d.n_deleted, d.overflow, cfg, pq_cfg, insert_chunk=insert_chunk,
+        block=block, use_sdc=use_sdc)
 
-    # ---- Phase 1: Delete (sequential block pass over the LTI) -------------
-    # Prune distances use PQ codes only (paper: "we use the compressed PQ
-    # vectors ... to calculate the approximate distances").
+
+def _delete_global(lti, delete_mask, cfg, pq_cfg, *, block, use_sdc):
+    """Phase 1 as one traced sweep over every block (Algorithm 4).  Prune
+    distances use PQ codes only (paper: "we use the compressed PQ vectors
+    ... to calculate the approximate distances")."""
+    g = lti.graph
     n_del = (g.active & delete_mask).sum()
     g = g._replace(deleted=g.deleted | (delete_mask & g.active))
     overflow = jnp.int32(0)
+    decoded = tables = None
     if use_sdc:
-        tables = pqm.sdc_tables(codebook)
-        decoded = None
+        tables = pqm.sdc_tables(lti.codebook)
         overflow = repair_cap_overflow(
             g.adjacency, g.deleted, g.active & ~g.deleted, SDC_REPAIR_CAP)
         g = consolidate_deletes_codes(g, cfg, lti.codes, tables,
                                       block=block, cap=SDC_REPAIR_CAP,
                                       mode="global")
     else:
-        decoded = pqm.decode(codebook, lti.codes, pq_cfg).astype(jnp.float32)
+        decoded = pqm.decode(lti.codebook, lti.codes, pq_cfg).astype(
+            jnp.float32)
         g = consolidate_deletes(g, cfg, block=block, prune_table=decoded,
                                 mode="global")
+    return DeletePhase(g, n_del, overflow, decoded, tables)
 
-    return _insert_patch_phases(
-        g, lti.codes, codebook, decoded, new_vecs, new_valid, n_del,
-        overflow, cfg, pq_cfg, insert_chunk=insert_chunk, block=block,
-        use_sdc=use_sdc)
+
+@functools.partial(jax.jit, static_argnames=("use_sdc",))
+def _open_delete(g, delete_mask, codebook, *, use_sdc):
+    """First slice of the local Delete phase: mark the DeleteList and make
+    the prune table (the SDC tables, or the full-precision table the
+    decode slices fill).  Returns (deleted, usable, n_del, table)."""
+    deleted = g.deleted | (delete_mask & g.active)
+    table = (pqm.sdc_tables(codebook) if use_sdc
+             else jnp.zeros(g.vectors.shape, jnp.float32))
+    return (deleted, g.active & ~deleted, (g.active & delete_mask).sum(),
+            table)
+
+
+@functools.partial(jax.jit, static_argnames=("pq_cfg", "rows"),
+                   donate_argnums=(0,))
+def _decode_range(table, codebook, codes, lo, *, pq_cfg, rows):
+    """Decode rows [lo, lo + rows) of the prune table into ``table``
+    (donated: the slices fill one buffer)."""
+    part = pqm.decode(codebook, jax.lax.dynamic_slice_in_dim(codes, lo, rows),
+                      pq_cfg).astype(jnp.float32)
+    return jax.lax.dynamic_update_slice_in_dim(table, part, lo, axis=0)
+
+
+def _delete_local(lti, delete_mask, cfg, pq_cfg, *, block, use_sdc,
+                  run: SliceRunner) -> DeletePhase:
+    """Phase 1 as bounded slices (module doc): the localized Algorithm 4,
+    bit-identical to ``_delete_global``."""
+    g0 = lti.graph
+    deleted, usable, n_del, table = run(
+        _open_delete, g0, delete_mask, lti.codebook, use_sdc=use_sdc)
+    aff, overflow = affected_rows(g0.adjacency, deleted, usable, run,
+                                  cap=SDC_REPAIR_CAP if use_sdc else None)
+    kernel = cfg.kernel_enabled()
+    if use_sdc:
+        decoded, tables = None, table
+
+        def repair(ids):
+            return _repair_slice_codes(g0.adjacency, lti.codes, tables,
+                                       deleted, usable, ids, cfg.alpha,
+                                       cfg.R, SDC_REPAIR_CAP, kernel)
+    else:
+        starts, rows = row_ranges(g0.capacity)
+        for lo in starts:
+            table = run(_decode_range, table, lti.codebook, lti.codes, lo,
+                        pq_cfg=pq_cfg, rows=rows)
+        decoded, tables = table, None
+
+        def repair(ids):
+            return _repair_slice_fp(g0.adjacency, decoded, deleted, usable,
+                                    ids, cfg.alpha, cfg.R, kernel)
+    g = repair_sliced(g0._replace(deleted=deleted), aff, block, repair, run)
+    return DeletePhase(g, n_del, jnp.int32(overflow), decoded, tables)
 
 
 def _streaming_merge_local(lti, new_vecs, new_valid, delete_mask, cfg, pq_cfg,
                            *, insert_chunk, block, use_sdc):
-    """Localized merge: eager affected-set Delete phase, then the SAME
-    jitted phases-2/3 body as the fused path.  Bit-identical results."""
-    g = lti.graph
-    codebook = lti.codebook
+    """Localized merge as bounded device slices (module doc); bit-identical
+    to the fused global merge."""
+    run = SliceRunner()
+    d = _delete_local(lti, delete_mask, cfg, pq_cfg, block=block,
+                      use_sdc=use_sdc, run=run)
+    # The adjacency stays out of the allocation program: returned
+    # unchanged it would be copied.
+    g, codes, decoded, slots, usable, c_slots, c_vecs = run(
+        _allocate_slice, d.graph._replace(adjacency=None), lti.codes,
+        lti.codebook, d.decoded, new_vecs, new_valid, pq_cfg,
+        insert_chunk=insert_chunk, use_sdc=use_sdc)
+    adjacency = d.graph.adjacency
+    pairs_j, pairs_p = [], []
+    for c in range(c_slots.shape[0]):
+        adjacency, pj, pp = run(
+            _insert_slice, adjacency, g.active, g.start, usable, codes,
+            lti.codebook, d.tables, decoded, c_slots, c_vecs, c, cfg,
+            use_sdc=use_sdc)
+        pairs_j.append(pj)
+        pairs_p.append(pp)
+    js, extra, has, n_ins, n_pairs, distinct = run(
+        _group_slice, pairs_j, pairs_p, slots, n_slots=g.capacity, R=cfg.R,
+        block=block)
+    for b in range(js.shape[0]):
+        adjacency = run(_patch_slice, adjacency, codes, d.tables, decoded,
+                        usable, js, extra, has, b, cfg, use_sdc=use_sdc)
+    g = g._replace(adjacency=adjacency)
+    P = len(pairs_j) * pairs_j[0].shape[0]
+    stats = MergeStats(d.n_deleted, n_ins, n_pairs, slots, d.overflow,
+                       distinct, jnp.int32(min(P, g.capacity)),
+                       slices=run.count)
+    return LTIState(g, codes, lti.codebook), stats
 
-    n_del = (g.active & delete_mask).sum()
-    g = g._replace(deleted=g.deleted | (delete_mask & g.active))
-    overflow = jnp.int32(0)
-    if use_sdc:
-        tables = pqm.sdc_tables(codebook)
-        decoded = None
-        overflow = repair_cap_overflow(
-            g.adjacency, g.deleted, g.active & ~g.deleted, SDC_REPAIR_CAP)
-        g = consolidate_deletes_codes(g, cfg, lti.codes, tables,
-                                      block=block, cap=SDC_REPAIR_CAP,
-                                      mode="local")
-    else:
-        decoded = pqm.decode(codebook, lti.codes, pq_cfg).astype(jnp.float32)
-        g = consolidate_deletes(g, cfg, block=block, prune_table=decoded,
-                                mode="local")
 
-    return _insert_patch_phases(
-        g, lti.codes, codebook, decoded, new_vecs, new_valid, n_del,
-        overflow, cfg, pq_cfg, insert_chunk=insert_chunk, block=block,
-        use_sdc=use_sdc)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "pq_cfg", "insert_chunk",
-                                              "block", "use_sdc"))
-def _insert_patch_phases(g, old_codes, codebook, decoded, new_vecs, new_valid,
-                         n_del, overflow, cfg, pq_cfg, *, insert_chunk, block,
-                         use_sdc):
-    """Phases 2 (Insert) + 3 (Patch), shared by the fused and localized
-    merge paths (inlined into the fused path's jit; the localized path's
-    own device program)."""
-    if use_sdc:
-        tables = pqm.sdc_tables(codebook)
-
-    # ---- Phase 2: Insert (random reads against the intermediate LTI) ------
+def _allocate(g, old_codes, codebook, decoded, new_vecs, new_valid, pq_cfg,
+              *, insert_chunk, use_sdc):
+    """Phase 2's opening: free slots for the staged rows, their codes,
+    vectors and flags stored, and the rows cut into insert chunks.
+    Returns (g, codes, decoded, slots, usable, c_slots, c_vecs)."""
     Nn = new_vecs.shape[0]
     # Allocate free slots for the new points (top_k over the free indicator
     # yields distinct, lowest-first free slots).
@@ -240,49 +322,122 @@ def _insert_patch_phases(g, old_codes, codebook, decoded, new_vecs, new_valid,
          jnp.zeros((pad, new_vecs.shape[1]), jnp.float32)])
     c_slots = c_slots.reshape(n_chunks, insert_chunk)
     c_vecs = c_vecs.reshape(n_chunks, insert_chunk, -1)
+    return g, codes, decoded, slots, usable, c_slots, c_vecs
 
+
+_allocate_slice = jax.jit(
+    _allocate, static_argnames=("pq_cfg", "insert_chunk", "use_sdc"),
+    donate_argnums=(3,))
+
+
+def _insert_chunk(adjacency, active, start, usable, codes, codebook, tables,
+                  decoded, sl, vv, cfg, *, use_sdc):
+    """One Insert-phase chunk: search + prune + scatter the new rows
+    ``sl`` (INVALID pads), returning the adjacency and the chunk's Delta
+    pair list."""
     backend = PQBackend(codes, codebook)
     use_kernel = cfg.kernel_enabled()
+    N = adjacency.shape[0]
+    if use_sdc:
+        # search via ADC; prune with d_p = exact-vector ADC and
+        # candidate-candidate distances via SDC on codes — one batched
+        # prune-engine call per insert chunk (fused kernel under
+        # use_kernel).
+        res = beam_search(adjacency, active, start, vv, backend,
+                          L=cfg.L_build,
+                          max_visits=cfg.visits_bound(cfg.L_build),
+                          beam_width=cfg.beam_width, use_kernel=use_kernel)
+        cand = jnp.concatenate([res.visited, res.ids], axis=1)
+        safe = jnp.maximum(cand, 0)
+        ok = (cand >= 0) & usable[safe] & (cand != sl[:, None])
+        d_p = jax.vmap(
+            lambda c, vec: pqm.adc(codes[c], pqm.lut(codebook, vec))
+        )(safe, vv)
+        new_adj = robust_prune_batch(
+            SDCPrune(codes, tables), cand, ok, alpha=cfg.alpha,
+            R=cfg.R, use_kernel=use_kernel, d_p=d_p).ids
+        src = jnp.broadcast_to(sl[:, None], new_adj.shape).reshape(-1)
+    else:
+        edges = compute_insert_edges(
+            adjacency, active, usable, start, decoded, sl, vv, backend,
+            L=cfg.L_build, max_visits=cfg.visits_bound(cfg.L_build),
+            alpha=cfg.alpha, R=cfg.R, beam_width=cfg.beam_width,
+            use_kernel=use_kernel)
+        new_adj = edges.new_adj
+        src = edges.pairs_p
+    valid = sl >= 0
+    new_adj = jnp.where(valid[:, None], new_adj, INVALID)
+    adjacency = adjacency.at[jnp.where(valid, sl, N)].set(
+        new_adj, mode="drop")
+    pj = new_adj.reshape(-1)
+    pp = jnp.where(pj >= 0, src, INVALID)
+    return adjacency, pj, pp
 
-    def insert_block(carry, inp):
-        adjacency = carry
-        sl, vv = inp
-        if use_sdc:
-            # search via ADC; prune with d_p = exact-vector ADC and
-            # candidate-candidate distances via SDC on codes — one batched
-            # prune-engine call per insert chunk (fused kernel under
-            # use_kernel).
-            res = beam_search(adjacency, g.active, g.start, vv, backend,
-                              L=cfg.L_build,
-                              max_visits=cfg.visits_bound(cfg.L_build),
-                              beam_width=cfg.beam_width,
-                              use_kernel=use_kernel)
-            cand = jnp.concatenate([res.visited, res.ids], axis=1)
-            safe = jnp.maximum(cand, 0)
-            ok = (cand >= 0) & usable[safe] & (cand != sl[:, None])
-            d_p = jax.vmap(
-                lambda c, vec: pqm.adc(codes[c], pqm.lut(codebook, vec))
-            )(safe, vv)
-            new_adj = robust_prune_batch(
-                SDCPrune(codes, tables), cand, ok, alpha=cfg.alpha,
-                R=cfg.R, use_kernel=use_kernel, d_p=d_p).ids
-            src = jnp.broadcast_to(sl[:, None],
-                                   new_adj.shape).reshape(-1)
-        else:
-            edges = compute_insert_edges(
-                adjacency, g.active, usable, g.start, decoded, sl, vv,
-                backend,
-                L=cfg.L_build, max_visits=cfg.visits_bound(cfg.L_build),
-                alpha=cfg.alpha, R=cfg.R, beam_width=cfg.beam_width,
-                use_kernel=use_kernel)
-            new_adj = edges.new_adj
-            src = edges.pairs_p
-        valid = sl >= 0
-        new_adj = jnp.where(valid[:, None], new_adj, INVALID)
-        adjacency = adjacency.at[jnp.where(valid, sl, g.capacity)].set(
-            new_adj, mode="drop")
-        pj = new_adj.reshape(-1)
-        pp = jnp.where(pj >= 0, src, INVALID)
+
+@functools.partial(jax.jit, static_argnames=("cfg", "use_sdc"),
+                   donate_argnums=(0,))
+def _insert_slice(adjacency, active, start, usable, codes, codebook, tables,
+                  decoded, c_slots, c_vecs, c, cfg, *, use_sdc):
+    """Insert chunk ``c`` of ``c_slots``/``c_vecs`` as one program, the
+    adjacency donated from chunk to chunk."""
+    return _insert_chunk(adjacency, active, start, usable, codes, codebook,
+                         tables, decoded, c_slots[c], c_vecs[c], cfg,
+                         use_sdc=use_sdc)
+
+
+def _delta_counts(slots, pairs_j, n_slots):
+    """(inserted rows, Delta pairs, DISTINCT Delta targets).  The
+    arrival-order Patch launches the fixed-shape worst case min(P, N)
+    prune rows regardless of how many targets actually collide — the gap
+    between the two numbers is the headroom the locality path cashes in."""
+    skey = jnp.sort(jnp.where(pairs_j >= 0, pairs_j, jnp.int32(n_slots)))
+    live = skey < n_slots
+    distinct = (live & jnp.concatenate(
+        [jnp.ones((1,), bool), skey[1:] != skey[:-1]])).sum()
+    return ((slots >= 0).sum(), (pairs_j >= 0).sum(),
+            distinct.astype(jnp.int32))
+
+
+@functools.partial(jax.jit, static_argnames=("n_slots", "R", "block"))
+def _group_slice(pairs_j, pairs_p, slots, *, n_slots, R, block):
+    """Delta grouped for the patch slices (``insert.patch_plan``), with
+    the merge's counts (``_delta_counts``)."""
+    pj, pp = jnp.concatenate(pairs_j), jnp.concatenate(pairs_p)
+    js, extra, has = patch_plan(pj, pp, n_slots, d_max=R, chunk=block)
+    return (js, extra, has) + _delta_counts(slots, pj, n_slots)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "use_sdc"),
+                   donate_argnums=(0,))
+def _patch_slice(adjacency, codes, tables, decoded, usable, js, extra, has, b,
+                 cfg, *, use_sdc):
+    """Patch-phase block ``b`` as one program, the adjacency donated from
+    block to block."""
+    backend = (SDCPrune(codes, tables) if use_sdc
+               else FullPrecisionPrune(decoded))
+    return patch_block(adjacency, backend, usable, js[b], extra[b], has[b],
+                       alpha=cfg.alpha, R=cfg.R,
+                       use_kernel=cfg.kernel_enabled())
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "pq_cfg", "insert_chunk",
+                                              "block", "use_sdc"))
+def _insert_patch_phases(g, old_codes, codebook, decoded, new_vecs, new_valid,
+                         n_del, overflow, cfg, pq_cfg, *, insert_chunk, block,
+                         use_sdc):
+    """Phases 2 (Insert) + 3 (Patch) as one program: the fused merge's
+    tail, scanning the same step functions the local merge launches one
+    slice at a time."""
+    tables = pqm.sdc_tables(codebook) if use_sdc else None
+    g, codes, decoded, slots, usable, c_slots, c_vecs = _allocate(
+        g, old_codes, codebook, decoded, new_vecs, new_valid, pq_cfg,
+        insert_chunk=insert_chunk, use_sdc=use_sdc)
+
+    # ---- Phase 2: Insert (random reads against the intermediate LTI) ------
+    def insert_block(adjacency, inp):
+        adjacency, pj, pp = _insert_chunk(
+            adjacency, g.active, g.start, usable, codes, codebook, tables,
+            decoded, *inp, cfg, use_sdc=use_sdc)
         return adjacency, (pj, pp)
 
     adjacency, (pairs_j, pairs_p) = jax.lax.scan(
@@ -291,6 +446,7 @@ def _insert_patch_phases(g, old_codes, codebook, decoded, new_vecs, new_valid,
     pairs_p = pairs_p.reshape(-1)
 
     # ---- Phase 3: Patch (sequential block pass applying Delta) ------------
+    use_kernel = cfg.kernel_enabled()
     if use_sdc:
         adjacency = apply_back_edges_codes(
             adjacency, codes, tables, usable, pairs_j, pairs_p,
@@ -301,18 +457,9 @@ def _insert_patch_phases(g, old_codes, codebook, decoded, new_vecs, new_valid,
             alpha=cfg.alpha, R=cfg.R, chunk=block, use_kernel=use_kernel)
 
     g = g._replace(adjacency=adjacency)
-    # Distinct Delta targets (device-side: sort + neighbor-compare).  The
-    # arrival-order Patch launches the fixed-shape worst case min(P, N)
-    # prune rows regardless of how many targets actually collide — the gap
-    # between the two numbers is the headroom the locality path cashes in.
-    skey = jnp.sort(jnp.where(pairs_j >= 0, pairs_j, jnp.int32(g.capacity)))
-    live = skey < g.capacity
-    distinct = (live & jnp.concatenate(
-        [jnp.ones((1,), bool), skey[1:] != skey[:-1]])).sum()
-    stats = MergeStats(n_del, (slots >= 0).sum(),
-                       (pairs_j >= 0).sum(), slots,
-                       jnp.asarray(overflow, jnp.int32),
-                       distinct.astype(jnp.int32),
+    n_ins, n_pairs, distinct = _delta_counts(slots, pairs_j, g.capacity)
+    stats = MergeStats(n_del, n_ins, n_pairs, slots,
+                       jnp.asarray(overflow, jnp.int32), distinct,
                        jnp.int32(min(pairs_j.shape[0], g.capacity)))
     return LTIState(g, codes, codebook), stats
 
@@ -320,7 +467,7 @@ def _insert_patch_phases(g, old_codes, codebook, decoded, new_vecs, new_valid,
 def _streaming_merge_ordered(lti, new_vecs, new_valid, delete_mask, cfg,
                              pq_cfg, *, insert_chunk, block, use_sdc, mode,
                              seed):
-    """Locality-schedule merge: eager Phase 1 (honoring ``mode``), then
+    """Locality-schedule merge: Phase 1 (honoring ``mode``), then
     Phase 2 as cluster-ordered chunks inserted EAGERLY — each chunk's Delta
     is applied before the next chunk searches.
 
@@ -341,35 +488,29 @@ def _streaming_merge_ordered(lti, new_vecs, new_valid, delete_mask, cfg,
     worst case.  Deterministic for fixed (inputs, seed): the ordering is
     seeded, chunking is sequential, and every launch size is a pure
     function of the data."""
-    g = lti.graph
     codebook = lti.codebook
+    run = SliceRunner()
 
     # ---- Phase 1: Delete (identical to the arrival-order paths) -----------
-    n_del = (g.active & delete_mask).sum()
-    g = g._replace(deleted=g.deleted | (delete_mask & g.active))
-    overflow = jnp.int32(0)
-    tables = decoded = None
-    if use_sdc:
-        tables = pqm.sdc_tables(codebook)
-        overflow = repair_cap_overflow(
-            g.adjacency, g.deleted, g.active & ~g.deleted, SDC_REPAIR_CAP)
-        g = consolidate_deletes_codes(g, cfg, lti.codes, tables,
-                                      block=block, cap=SDC_REPAIR_CAP,
-                                      mode=mode)
+    if mode == "local":
+        d = _delete_local(lti, delete_mask, cfg, pq_cfg, block=block,
+                          use_sdc=use_sdc, run=run)
     else:
-        decoded = pqm.decode(codebook, lti.codes, pq_cfg).astype(jnp.float32)
-        g = consolidate_deletes(g, cfg, block=block, prune_table=decoded,
-                                mode=mode)
+        d = _delete_global(lti, delete_mask, cfg, pq_cfg, block=block,
+                           use_sdc=use_sdc)
+    g, tables = d.graph, d.tables
 
     # ---- Phase 2a: order + allocate + store (one jitted stage) ------------
     # Rows the Delete phase already rewrote (freed slots go all-INVALID,
     # repaired neighbors change) mark their 4KB topology blocks dirty for
     # this merge's patch: placing new points there costs ZERO extra block
     # writes (the DGAI placement observation).
-    phase1_dirty = adjacency_delta_mask(lti.graph.adjacency, g.adjacency)
-    g, codes, decoded, slots_ord, ord_vecs, perm = _locality_stage(
-        g, lti.codes, codebook, decoded, new_vecs, new_valid, phase1_dirty,
-        jax.random.PRNGKey(seed), cfg, pq_cfg, use_sdc=use_sdc)
+    phase1_dirty = run(adjacency_delta_mask, lti.graph.adjacency,
+                       g.adjacency)
+    g, codes, decoded, slots_ord, ord_vecs, perm = run(
+        _locality_stage, g, lti.codes, codebook, d.decoded, new_vecs,
+        new_valid, phase1_dirty, jax.random.PRNGKey(seed), cfg, pq_cfg,
+        use_sdc=use_sdc)
     usable = g.active & ~g.deleted
 
     Nn = new_vecs.shape[0]
@@ -388,9 +529,10 @@ def _streaming_merge_ordered(lti, new_vecs, new_valid, delete_mask, cfg,
     n_pairs = n_targets = n_rows = 0
     cap_max = min(insert_chunk * cfg.R, g.capacity)
     for c in range(n_chunks):
-        adjacency, pj, pp = _ordered_insert_chunk(
-            adjacency, g.active, g.start, usable, codes, codebook, tables,
-            decoded, c_slots[c], c_vecs[c], cfg, use_sdc=use_sdc)
+        adjacency, pj, pp = run(
+            _insert_slice, adjacency, g.active, g.start, usable, codes,
+            codebook, tables, decoded, c_slots, c_vecs, c, cfg,
+            use_sdc=use_sdc)
         pj_h = np.asarray(pj)
         d_c = int(np.unique(pj_h[pj_h >= 0]).size)
         n_pairs += int((pj_h >= 0).sum())
@@ -399,18 +541,20 @@ def _streaming_merge_ordered(lti, new_vecs, new_valid, delete_mask, cfg,
         bucket = next_bucket(d_c, cap=cap_max)
         n_targets += d_c
         n_rows += bucket
-        adjacency = _ordered_patch(
-            adjacency, codes, tables, decoded, usable, pj, pp, cfg,
-            bucket=bucket, block=block, use_sdc=use_sdc)
+        adjacency = run(
+            _ordered_patch, adjacency, codes, tables, decoded, usable, pj,
+            pp, cfg, bucket=bucket, block=block, use_sdc=use_sdc)
     g = g._replace(adjacency=adjacency)
 
     # Report slots in ORIGINAL row order (perm is a permutation, so the
     # scatter covers every entry): staged row i landed in slot
     # slots_orig[i], whatever position the ordering gave it.
     slots_orig = jnp.full((Nn,), INVALID, jnp.int32).at[perm].set(slots_ord)
-    stats = MergeStats(n_del, (slots_ord >= 0).sum(), jnp.int32(n_pairs),
-                       slots_orig, jnp.asarray(overflow, jnp.int32),
-                       jnp.int32(n_targets), jnp.int32(n_rows))
+    stats = MergeStats(d.n_deleted, (slots_ord >= 0).sum(),
+                       jnp.int32(n_pairs), slots_orig,
+                       jnp.asarray(d.overflow, jnp.int32),
+                       jnp.int32(n_targets), jnp.int32(n_rows),
+                       slices=run.count)
     return LTIState(g, codes, codebook), stats
 
 
@@ -418,8 +562,7 @@ def _streaming_merge_ordered(lti, new_vecs, new_valid, delete_mask, cfg,
 def _locality_stage(g, old_codes, codebook, decoded, new_vecs, new_valid,
                     phase1_dirty, key, cfg, pq_cfg, *, use_sdc):
     """Proximity-order the staged rows, allocate slots along the ordering,
-    and store vectors/codes/flags — the ordered twin of the staging prologue
-    in ``_insert_patch_phases``.
+    and store vectors/codes/flags — the ordered twin of ``_allocate``.
 
     Slot placement is locality-aware (two DGAI-style effects on the delta
     patch): free slots inside ALREADY-DIRTY 4KB topology blocks
@@ -468,49 +611,6 @@ def _locality_stage(g, old_codes, codebook, decoded, new_vecs, new_valid,
                        g.n_total,
                        jnp.max(jnp.where(slots >= 0, slots, -1)) + 1))
     return g, codes, decoded, slots, ord_vecs, perm
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "use_sdc"))
-def _ordered_insert_chunk(adjacency, active, start, usable, codes, codebook,
-                          tables, decoded, sl, vv, cfg, *, use_sdc):
-    """One locality-schedule insert chunk: search + prune + scatter the new
-    rows, returning the chunk's Delta pair list.  The traced body mirrors
-    ``insert_block`` inside ``_insert_patch_phases`` — the difference is
-    purely the call schedule (eager, so this chunk sees every previously
-    patched chunk)."""
-    backend = PQBackend(codes, codebook)
-    use_kernel = cfg.kernel_enabled()
-    N = adjacency.shape[0]
-    if use_sdc:
-        res = beam_search(adjacency, active, start, vv, backend,
-                          L=cfg.L_build,
-                          max_visits=cfg.visits_bound(cfg.L_build),
-                          beam_width=cfg.beam_width, use_kernel=use_kernel)
-        cand = jnp.concatenate([res.visited, res.ids], axis=1)
-        safe = jnp.maximum(cand, 0)
-        ok = (cand >= 0) & usable[safe] & (cand != sl[:, None])
-        d_p = jax.vmap(
-            lambda c, vec: pqm.adc(codes[c], pqm.lut(codebook, vec))
-        )(safe, vv)
-        new_adj = robust_prune_batch(
-            SDCPrune(codes, tables), cand, ok, alpha=cfg.alpha,
-            R=cfg.R, use_kernel=use_kernel, d_p=d_p).ids
-        src = jnp.broadcast_to(sl[:, None], new_adj.shape).reshape(-1)
-    else:
-        edges = compute_insert_edges(
-            adjacency, active, usable, start, decoded, sl, vv, backend,
-            L=cfg.L_build, max_visits=cfg.visits_bound(cfg.L_build),
-            alpha=cfg.alpha, R=cfg.R, beam_width=cfg.beam_width,
-            use_kernel=use_kernel)
-        new_adj = edges.new_adj
-        src = edges.pairs_p
-    valid = sl >= 0
-    new_adj = jnp.where(valid[:, None], new_adj, INVALID)
-    adjacency = adjacency.at[jnp.where(valid, sl, N)].set(
-        new_adj, mode="drop")
-    pj = new_adj.reshape(-1)
-    pp = jnp.where(pj >= 0, src, INVALID)
-    return adjacency, pj, pp
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "bucket", "block",

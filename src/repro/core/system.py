@@ -154,6 +154,8 @@ class SystemStats:
     merges: int = 0
     snapshots: int = 0
     merge_seconds: float = 0.0
+    merge_slices: int = 0       # device programs the merges ran one at a
+    #   time, each waited out before the next (MergeStats.slices)
     # Jitted device programs launched by the query path (the §5.2 fan-out's
     # serving-cost metric).  Contract under batching: B queries served in
     # one launch count ONE dispatch — the unified path pays 1 per
@@ -1230,18 +1232,20 @@ class FreshDiskANN:
                 self._merge_inflight = sum(t.n for t in ro)
             span.set_metadata(staged=self._merge_inflight)
             try:
-                deletes, repair_mode = self._merge_body(ro, t0)
-                span.set_metadata(deletes=deletes, repair_mode=repair_mode)
+                deletes, repair_mode, slices = self._merge_body(ro, t0)
+                span.set_metadata(deletes=deletes, repair_mode=repair_mode,
+                                  slices=slices)
             finally:
                 # A failed merge must not leave the in-flight count set, or
                 # every future threshold check would under-count and no
                 # merge would ever run again.
                 self._merge_inflight = 0
 
-    def _merge_body(self, ro: list, t0: float) -> tuple[int, str]:
+    def _merge_body(self, ro: list, t0: float) -> tuple[int, str, int]:
         """One StreamingMerge of the snapshotted RO tiers ``ro``; returns
-        the DeleteList size it consumed against and its Delete phase's
-        repair mode, for the ``fdann.merge`` span."""
+        the DeleteList size it consumed against, its Delete phase's repair
+        mode and the device programs it ran, for the ``fdann.merge``
+        span."""
         staged = sum(t.n for t in ro)
         icfg = self.cfg.index
         merge = self.stats.merges       # every phase span carries it
@@ -1304,6 +1308,7 @@ class FreshDiskANN:
                 stats.repair_cap_overflows)
             self.stats.merge_backedge_targets += int(stats.n_backedge_targets)
             self.stats.merge_prune_rows += int(stats.n_prune_rows)
+            self.stats.merge_slices += int(stats.slices)
             if repair_mode == "local":
                 self.stats.local_repairs += 1
             else:
@@ -1387,7 +1392,7 @@ class FreshDiskANN:
         self.stats.merge_seconds += time.perf_counter() - t0
         with TraceAnnotation("fdann.merge.probe", merge=merge):
             self._probe_reachability(repair_mode)
-        return len(del_snapshot), repair_mode
+        return len(del_snapshot), repair_mode, int(stats.slices)
 
     def _pick_repair_mode(self, dmask: np.ndarray) -> str:
         """Route the merge's Delete phase: the localized affected-set sweep

@@ -27,7 +27,7 @@ METADATA = {
     "fdann.search.prepare": {"batch", "lanes"},
     "fdann.search.device": {"batch", "max_hops", "p50_hops"},
     "fdann.flush": {"points", "chunks"},
-    "fdann.merge": {"merge", "staged", "deletes", "repair_mode"},
+    "fdann.merge": {"merge", "staged", "deletes", "repair_mode", "slices"},
     **{p: {"merge"} for p in MERGE_PHASES},
 }
 
@@ -90,7 +90,7 @@ def traced(points, queries, tmp_path_factory):
 
 
 def test_every_span_appears_with_its_metadata(traced):
-    _, events = traced
+    sys_, events = traced
     seen = {}
     for ev in events:
         if ev[1] in METADATA:
@@ -110,6 +110,9 @@ def test_every_span_appears_with_its_metadata(traced):
     merge, = seen["fdann.merge"]
     assert merge[4]["deletes"] == 3 and merge[4]["staged"] > 0
     assert merge[4]["repair_mode"] in ("local", "global")
+    assert sys_.stats.merges == 1
+    assert merge[4]["slices"] == sys_.stats.merge_slices
+    assert (merge[4]["slices"] > 1) == (merge[4]["repair_mode"] == "local")
     for name in MERGE_PHASES:
         phase, = seen[name]
         assert _inside(phase, merge)

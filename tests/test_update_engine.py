@@ -16,6 +16,7 @@ import dataclasses
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import index as mem
@@ -24,7 +25,7 @@ from repro.core.delete import consolidate_deletes, delete
 from repro.core.distance import INVALID
 from repro.core.insert import apply_back_edges
 from repro.core.lti import build_lti
-from repro.core.merge import streaming_merge
+from repro.core.merge import MergeStats, streaming_merge
 from repro.core.prune import check_alpha_rng
 
 from conftest import DIM
@@ -254,26 +255,169 @@ def test_localized_consolidate_codes_bit_parity(points):
         assert int(a.start) == int(b.start)
 
 
-@pytest.mark.parametrize("use_sdc", [False, True])
-def test_streaming_merge_local_parity(points, use_sdc):
-    """A localized merge (eager Delete phase + jitted phases 2/3) must be
-    bit-identical to the fused global merge."""
+def _n_affected(lti, dmask):
+    """Rows the merge's Delete phase repairs for the DeleteList ``dmask``."""
+    from repro.core.delete import affected_mask
+    g = lti.graph
+    deleted = g.deleted | (dmask & g.active)
+    return int(affected_mask(g.adjacency, deleted,
+                             g.active & ~deleted).sum())
+
+
+def _local_merge_slices(n_aff, block, capacity, n_new, insert_chunk, R,
+                        use_sdc):
+    """The device programs a local merge runs (core/merge.py's doc): the
+    opening one; the affected-set ranges and, in full precision, as many
+    decode ranges; one repair and one write per block of affected rows;
+    the reclamation; the allocation; one per insert chunk; the grouping;
+    one per patch block."""
+    from repro.core.delete import RANGE_ROWS
+    ranges = -(-capacity // min(RANGE_ROWS, capacity))
+    chunks = -(-n_new // insert_chunk)
+    targets = min(chunks * insert_chunk * R, capacity)
+    return (1 + ranges * (1 if use_sdc else 2) + 2 * -(-n_aff // block)
+            + 1 + 1 + chunks + 1 + -(-targets // min(targets, block)))
+
+
+def _exact_block(n):
+    """A block size that ``n`` fills exactly, in two slices or more where
+    ``n`` has a divisor."""
+    d = next((d for d in range(2, n) if n % d == 0), 1)
+    return n // d
+
+
+# (use_sdc, which deletes, block, rows per range slice): the first two are
+# the original cases, whose affected rows leave their one slice partial.
+# 1,000-row ranges cover the 1,024 rows in two slices that overlap.
+LOCAL_CASES = {
+    "False": (False, "some", 256, None), "True": (True, "some", 256, None),
+    "False-none": (False, "none", 256, None),
+    "True-none": (True, "none", 256, None),
+    "False-exact": (False, "some", "exact", None),
+    "True-exact": (True, "some", "exact", None),
+    "False-partial3": (False, "some", 64, None),
+    "True-partial3": (True, "some", 64, None),
+    "False-ranges": (False, "some", 64, 1000),
+    "True-ranges": (True, "some", 64, 1000),
+}
+
+
+@pytest.mark.parametrize("use_sdc, deletes, block, range_rows",
+                         LOCAL_CASES.values(), ids=LOCAL_CASES.keys())
+def test_streaming_merge_local_parity(points, monkeypatch, use_sdc, deletes,
+                                      block, range_rows):
+    """A localized merge (bounded device slices) must be bit-identical to
+    the fused global merge — graph, codes, slots and every MergeStats
+    count — with no affected rows, a count that fills its last repair
+    slice exactly, one that leaves it partial, and range slices that
+    overlap; and it runs the expected number of slices, where the fused
+    merge runs one program."""
+    if range_rows:
+        monkeypatch.setattr("repro.core.delete.RANGE_ROWS", range_rows)
     cfg, pq_cfg = _cfg(False), _pq()
     lti = build_lti(points[:300], cfg, pq_cfg, batch=64)
     newv = jnp.asarray(points[300:400])
     valid = jnp.ones((100,), bool)
-    dmask = jnp.zeros((1024,), bool).at[jnp.arange(0, 300, 11)].set(True)
+    dmask = jnp.zeros((1024,), bool)
+    if deletes == "some":
+        dmask = dmask.at[jnp.arange(0, 300, 11)].set(True)
+    n_aff = _n_affected(lti, dmask)
+    assert (n_aff > 0) == (deletes == "some")
+    if block == "exact":
+        block = _exact_block(n_aff)
+        assert n_aff % block == 0
+    elif n_aff:
+        assert n_aff % block != 0
     m_g, s_g = streaming_merge(lti, newv, valid, dmask, cfg, pq_cfg,
-                               insert_chunk=32, block=256, use_sdc=use_sdc,
+                               insert_chunk=32, block=block, use_sdc=use_sdc,
                                repair_mode="global")
     m_l, s_l = streaming_merge(lti, newv, valid, dmask, cfg, pq_cfg,
-                               insert_chunk=32, block=256, use_sdc=use_sdc,
+                               insert_chunk=32, block=block, use_sdc=use_sdc,
                                repair_mode="local")
-    np.testing.assert_array_equal(np.asarray(m_g.graph.adjacency),
-                                  np.asarray(m_l.graph.adjacency))
-    np.testing.assert_array_equal(np.asarray(s_g.slots),
-                                  np.asarray(s_l.slots))
-    assert int(s_g.repair_cap_overflows) == int(s_l.repair_cap_overflows)
+    for a, b in zip(jax.tree.leaves(m_g), jax.tree.leaves(m_l)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for f in MergeStats._fields[:-1]:
+        np.testing.assert_array_equal(np.asarray(getattr(s_g, f)),
+                                      np.asarray(getattr(s_l, f)), f)
+    assert int(s_g.slices) == 1
+    assert s_l.slices == _local_merge_slices(n_aff, block, 1024, 100, 32,
+                                             cfg.R, use_sdc)
+
+
+@pytest.mark.parametrize("use_sdc", [False, True])
+def test_local_merge_compiles_its_slices_once(points, use_sdc):
+    """Every slice of the local merge has a fixed shape: a second merge
+    with another affected-row count (and so another number of repair and
+    write slices) adds no entry to any slice program's jit cache."""
+    from repro.core import delete as dl
+    from repro.core import merge as mg
+    cfg, pq_cfg = _cfg(False), _pq()
+    lti = build_lti(points[:300], cfg, pq_cfg, batch=64)
+    newv = jnp.asarray(points[300:364])
+    valid = jnp.ones((64,), bool)
+    programs = (mg._open_delete, dl._affected_range, mg._decode_range,
+                dl._repair_slice_fp, dl._repair_slice_codes, dl._reclaim,
+                dl._write_rows, mg._allocate_slice, mg._insert_slice,
+                mg._group_slice, mg._patch_slice)
+    counts, sizes = [], []
+    for step in (29, 5):
+        dmask = jnp.zeros((1024,), bool).at[jnp.arange(0, 300, step)].set(
+            True)
+        counts.append(_n_affected(lti, dmask))
+        _, stats = streaming_merge(lti, newv, valid, dmask, cfg, pq_cfg,
+                                   insert_chunk=32, block=32,
+                                   use_sdc=use_sdc, repair_mode="local")
+        sizes.append([p._cache_size() for p in programs])
+    assert -(-counts[0] // 32) < -(-counts[1] // 32)
+    assert sizes[0] == sizes[1]
+
+
+def test_system_merge_counts_its_slices(points):
+    """``SystemStats.merge_slices`` adds up the merges' slices: a system's
+    local-repair merge runs the count its sizes give."""
+    from repro.core.config import SystemConfig
+    from repro.core.system import bootstrap_system
+    scfg = SystemConfig(index=_cfg(False), pq=_pq(), ro_snapshot_points=32,
+                        merge_threshold=10**6, temp_capacity=256,
+                        insert_batch=32, merge_block=64,
+                        local_repair_threshold=0.5)
+    sys_ = bootstrap_system(points[:300], np.arange(300), scfg, batch=64)
+    for e in range(0, 300, 13):
+        sys_.delete(e)
+    for i in range(64):
+        sys_.insert(1000 + i, points[300 + i])
+    lti = sys_.lti
+    dmask = jnp.asarray(np.isin(sys_.lti_ext_ids, list(sys_.deleted_ext))
+                        & np.asarray(lti.graph.active))
+    staged = sum(t.n for t in sys_.ro)
+    n_aff = _n_affected(lti, dmask)
+    assert staged > 0 and n_aff > 64
+    sys_.merge()
+    assert (sys_.stats.merges, sys_.stats.local_repairs) == (1, 1)
+    assert sys_.stats.merge_slices == _local_merge_slices(
+        n_aff, 64, 1024, staged, 32, scfg.index.R, False)
+
+
+def test_harness_local_repair_warm_up_runs(points):
+    """The benchmark's set-up compiles the localized Delete phase through
+    ``bench/harness/window.py::Cell.warm_local_repair``, which calls
+    ``delete._repair_blocks_fp`` and ``delete._scatter_repaired`` by
+    name: it must keep running on a tiny system."""
+    import os
+    import sys
+    from repro.core.config import SystemConfig
+    from repro.core.system import bootstrap_system
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness.window import Cell
+    scfg = SystemConfig(index=_cfg(False), pq=_pq(), merge_block=64)
+    cell = Cell(dict(k=5, merge_block=64, R=scfg.index.R,
+                     alpha=scfg.index.alpha), None, lambda m: None)
+    cell.sys = bootstrap_system(points[:300], np.arange(300), scfg)
+    cell.merge_deleted = np.arange(0, 300, 7)
+    cell.warm_local_repair()
 
 
 def test_localized_rows_satisfy_alpha_rng(points):
@@ -363,7 +507,7 @@ def test_merge_delete_everything_then_reinsert(points):
         assert int(g.active.sum()) == 64
 
 
-def test_repair_cap_overflow_counter(points):
+def test_repair_cap_overflow_counter(points, monkeypatch):
     """A node with more deleted out-neighbors than the SDC expansion cap
     must fire the overflow counter, and its repaired row must still shed
     every deleted edge (the keep-mask is uncapped)."""
@@ -395,4 +539,22 @@ def test_repair_cap_overflow_counter(points):
     _, stats = streaming_merge(lti, none, jnp.zeros((1,), bool), dmask,
                                cfg, _pq(), insert_chunk=32, block=256,
                                use_sdc=True)
+    assert int(stats.repair_cap_overflows) == n_over
+    # ... and so does a local one whose range slices overlap (1,000-row
+    # slices over 1,024 rows: the second starts at row 24), for a node
+    # both slices cover: it counts once.
+    monkeypatch.setattr("repro.core.delete.RANGE_ROWS", 1000)
+    deg = np.asarray((g.adjacency >= 0).sum(axis=1))
+    p = 24 + int(np.argmax(deg[24:]))
+    row = np.asarray(g.adjacency[p])
+    victims = row[row >= 0][:SDC_REPAIR_CAP + 2].astype(np.int32)
+    gd = delete(g, jnp.asarray(victims))
+    n_over = int(repair_cap_overflow(gd.adjacency, gd.deleted,
+                                     gd.active & ~gd.deleted,
+                                     SDC_REPAIR_CAP))
+    assert n_over >= 1
+    dmask = jnp.zeros((1024,), bool).at[jnp.asarray(victims)].set(True)
+    _, stats = streaming_merge(lti, none, jnp.zeros((1,), bool), dmask,
+                               cfg, _pq(), insert_chunk=32, block=256,
+                               use_sdc=True, repair_mode="local")
     assert int(stats.repair_cap_overflows) == n_over
